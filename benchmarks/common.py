@@ -18,8 +18,11 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import subprocess
+import sys
 import time
-from typing import Dict, List, Sequence, Tuple
+from pathlib import Path
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -33,6 +36,7 @@ from repro.fedsim.pretrain import pretrain_to_target
 from repro.models import mlp
 
 RESULTS_DIR = os.environ.get("REPRO_RESULTS", "results")
+ROOT = Path(__file__).resolve().parents[1]
 
 
 def bench_scale() -> Dict[str, int]:
@@ -168,3 +172,35 @@ def run_fed_avg_seeds(spec: ScenarioSpec, *, n_seeds: int = 2,
 
 def csv_row(name: str, us_per_call: float, derived: str) -> str:
     return f"{name},{us_per_call:.1f},{derived}"
+
+
+def run_cpu_child(args: Sequence[str], *, devices: int = 0,
+                  timeout: float = 1800,
+                  env: Optional[Dict[str, str]] = None) -> str:
+    """Run ``python *args`` from the repo root in a child process on the
+    CPU backend, with ``devices`` forced host devices when > 0; returns
+    its stdout and raises with its stderr tail on a non-zero exit.
+
+    A chip belongs to one process at a time.  Where this process runs on
+    an accelerator it holds the chip, and a child that needs a device
+    could only fail or hang, so this refuses at once: run the suite's
+    module on its own there, which runs the cell in its own process on the
+    visible devices."""
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"{' '.join(args[:2])}: this cell runs in a CPU child process, "
+            f"and this process holds the {backend} device; run the module "
+            f"on its own (python -m benchmarks.<suite>)")
+    child = dict(os.environ, **(env or {}), JAX_PLATFORMS="cpu")
+    child["PYTHONPATH"] = str(ROOT / "src") + os.pathsep \
+        + child.get("PYTHONPATH", "")
+    if devices:
+        child["XLA_FLAGS"] = (child.get("XLA_FLAGS", "") + " --xla_force_"
+                              f"host_platform_device_count={devices}")
+    out = subprocess.run([sys.executable, *args], capture_output=True,
+                         text=True, timeout=timeout, env=child, cwd=ROOT)
+    if out.returncode != 0:
+        raise RuntimeError(f"{' '.join(args[:2])} failed:\n"
+                           f"{out.stderr[-2000:]}")
+    return out.stdout

@@ -26,7 +26,8 @@ that the device working set is bounded by (chunk x N) + (R x tile), not
 
 Standalone:
   PYTHONPATH=src python -m benchmarks.nshard_round --devices 8
-Via the harness:
+Via the harness (the cell as an 8-device CPU child process; on an
+accelerator the harness refuses it, since its process holds the chip):
   PYTHONPATH=src python -m benchmarks.run --only nshard
 """
 from __future__ import annotations
@@ -35,7 +36,6 @@ import argparse
 import dataclasses
 import json
 import os
-import subprocess
 import sys
 import time
 from pathlib import Path
@@ -238,20 +238,12 @@ def _csv_rows(rec: dict) -> List[str]:
 
 
 def run() -> List[str]:
-    """Harness entry: one 8-device subprocess (device count must be fixed
-    before jax initializes, as in benchmarks/sharded_round)."""
-    here = Path(__file__).resolve().parents[1]
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8")
-    env["PYTHONPATH"] = str(here / "src") + os.pathsep \
-        + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.nshard_round", "--devices", "8"],
-        capture_output=True, text=True, timeout=1800, env=env, cwd=str(here))
-    if out.returncode != 0:
-        raise RuntimeError(f"nshard cell failed:\n{out.stderr[-2000:]}")
-    return [ln for ln in out.stdout.splitlines()
+    """Harness entry: one 8-device CPU child process (the device count
+    must be fixed before jax initializes, as in benchmarks/sharded_round)."""
+    from benchmarks.common import run_cpu_child
+    out = run_cpu_child(["-m", "benchmarks.nshard_round", "--devices", "8"],
+                        devices=8)
+    return [ln for ln in out.splitlines()
             if ln.startswith("nshard_round/")]
 
 

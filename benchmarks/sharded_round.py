@@ -16,7 +16,8 @@ Standalone:
   PYTHONPATH=src python -m benchmarks.sharded_round --devices 8 \
       [--agents 16 --rsus 4 --rounds 2 --out results/bench]
 
-Via the harness (spawns the 1- and 8-device cells):
+Via the harness (the 1- and 8-device cells as CPU child processes; on an
+accelerator the harness refuses them, since its process holds the chip):
   PYTHONPATH=src python -m benchmarks.run --only sharded
 """
 from __future__ import annotations
@@ -24,7 +25,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import time
 from pathlib import Path
@@ -169,24 +169,14 @@ def _csv_rows(rec: dict) -> List[str]:
 
 
 def run() -> List[str]:
-    """Harness entry (benchmarks.run): spawn one subprocess per device
-    count so each cell gets a fresh jax with the forced device count."""
+    """Harness entry (benchmarks.run): one CPU child process per device
+    count, so each cell gets a fresh jax with the forced device count."""
+    from benchmarks.common import run_cpu_child
     rows: List[str] = []
-    here = Path(__file__).resolve().parents[1]
     for n_dev in DEFAULT_DEVICES:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                            + f" --xla_force_host_platform_device_count={n_dev}")
-        env["PYTHONPATH"] = str(here / "src") + os.pathsep \
-            + env.get("PYTHONPATH", "")
-        out = subprocess.run(
-            [sys.executable, "-m", "benchmarks.sharded_round",
-             "--devices", str(n_dev)],
-            capture_output=True, text=True, timeout=1200, env=env,
-            cwd=str(here))
-        if out.returncode != 0:
-            raise RuntimeError(f"d{n_dev} cell failed:\n{out.stderr[-2000:]}")
-        rows.extend(ln for ln in out.stdout.splitlines()
+        out = run_cpu_child(["-m", "benchmarks.sharded_round", "--devices",
+                             str(n_dev)], devices=n_dev, timeout=1200)
+        rows.extend(ln for ln in out.splitlines()
                     if ln.startswith("sharded_round/"))
     return rows
 

@@ -19,11 +19,12 @@ Two PR-8 cells ride in the same record:
                   widened static_key keeps in ONE group: walls, actual
                   trace count (``core.program_cache`` counters; CI pins 1)
                   and equivalence vs sequential;
-  cold_warm     — the same small grid run in two fresh subprocesses
-                  sharing one ``REPRO_CACHE_DIR``: the first pays XLA
-                  compilation and populates the persistent cache, the
-                  second loads from disk — ``cold_vs_warm_wall`` is the
-                  ratio CI asserts ≥ 2×.
+  cold_warm     — the same small grid run in two fresh CPU child
+                  processes sharing one persistent compilation cache at a
+                  fixed, wiped path (``COLD_WARM_DIR``): the first pays
+                  XLA compilation and populates it, the second loads
+                  from disk — ``cold_vs_warm_wall`` is the ratio CI
+                  asserts ≥ 2×.
 
 Standalone:
   PYTHONPATH=src python -m benchmarks.sweep_bench [--rounds 3] [--agents 16]
@@ -35,9 +36,7 @@ import dataclasses
 import json
 import os
 import shutil
-import subprocess
 import sys
-import tempfile
 import textwrap
 import time
 from pathlib import Path
@@ -45,6 +44,10 @@ from typing import List
 
 CSRS = (1.0, 0.5, 0.2, 0.1)
 CADENCES = ((2, 1, 0), (3, 2, 2), (1, 2, 3))   # (lar, local_epochs, ce)
+# the cold/warm cell's own compilation cache, inside the git-ignored
+# default cache directory and wiped before the cold run
+COLD_WARM_DIR = (Path(__file__).resolve().parents[1] / ".jax_cache"
+                 / "cold_warm")
 
 
 def _parse_args():
@@ -233,27 +236,23 @@ _COLD_WARM_CHILD = textwrap.dedent("""
 
 
 def run_cold_warm(args) -> dict:
-    """Persistent-compilation-cache story: the same sweep in two fresh
-    processes sharing one ``REPRO_CACHE_DIR``.  The first (cold) pays XLA
-    compilation and writes the disk cache; the second (warm) re-traces but
-    loads the compiled executables.  The cache dir is wiped first so the
-    cold run is genuinely cold even under CI's restored cache volume."""
-    cache_dir = Path(tempfile.mkdtemp(prefix="repro-coldwarm-"))
-    env = dict(os.environ, REPRO_CACHE_DIR=str(cache_dir))
-    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+    """Persistent-compilation-cache story: the same sweep in two fresh CPU
+    child processes sharing one ``JAX_COMPILATION_CACHE_DIR``.  The first
+    (cold) pays XLA compilation and writes the disk cache; the second
+    (warm) re-traces but loads the compiled executables.  The directory is
+    wiped first so the cold run is genuinely cold even under CI's restored
+    cache volume."""
+    from benchmarks.common import run_cpu_child
+    shutil.rmtree(COLD_WARM_DIR, ignore_errors=True)
+    env = {"JAX_COMPILATION_CACHE_DIR": str(COLD_WARM_DIR)}
     walls, accs = [], []
-    try:
-        for _ in ("cold", "warm"):
-            out = subprocess.run(     # 1 round: the wall IS compile time
-                [sys.executable, "-c", _COLD_WARM_CHILD,
-                 str(args.agents), "1"],
-                env=env, capture_output=True, text=True, check=True)
-            rec = json.loads(out.stdout.strip().splitlines()[-1])
-            walls.append(rec["wall"])
-            accs.append(rec["acc"])
-        entries = sum(1 for _ in cache_dir.iterdir())
-    finally:
-        shutil.rmtree(cache_dir, ignore_errors=True)
+    for _ in ("cold", "warm"):
+        out = run_cpu_child(     # 1 round: the wall IS compile time
+            ["-c", _COLD_WARM_CHILD, str(args.agents), "1"], env=env)
+        rec = json.loads(out.strip().splitlines()[-1])
+        walls.append(rec["wall"])
+        accs.append(rec["acc"])
+    entries = sum(1 for _ in COLD_WARM_DIR.iterdir())
     assert accs[0] == accs[1], "cached program changed the math"
     return {
         "cold_s": walls[0],
@@ -287,7 +286,7 @@ def _csv_rows(rec: dict) -> List[str]:
     if cw:
         rows += [
             csv_row("sweep_round/cold_wall", cw["cold_s"] * 1e6,
-                    "fresh process, empty REPRO_CACHE_DIR"),
+                    "fresh process, empty compilation cache"),
             csv_row("sweep_round/warm_wall", cw["warm_s"] * 1e6,
                     f"cold/warm={cw['cold_vs_warm_wall']:.2f}x"),
         ]
@@ -295,9 +294,11 @@ def _csv_rows(rec: dict) -> List[str]:
 
 
 def _record(args) -> dict:
+    # the child-process cell first: off the CPU it refuses before any work
+    cold_warm = run_cold_warm(args)
     rec = run_cell(args)
     rec["mixed_cadence"] = run_mixed(args)
-    rec["cold_warm"] = run_cold_warm(args)
+    rec["cold_warm"] = cold_warm
     out_dir = Path(args.out)
     out_dir.mkdir(parents=True, exist_ok=True)
     path = out_dir / "sweep_round.json"

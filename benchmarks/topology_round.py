@@ -18,7 +18,8 @@ Standalone:
   PYTHONPATH=src python -m benchmarks.topology_round --devices 8 \
       [--agents 64 --rsus 32 --rounds 2 --out results/bench]
 
-Via the harness (spawns the 8-device cell):
+Via the harness (the 8-device cell as a CPU child process; on an
+accelerator the harness refuses it, since its process holds the chip):
   PYTHONPATH=src python -m benchmarks.run --only topology
 """
 from __future__ import annotations
@@ -26,7 +27,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import time
 from pathlib import Path
@@ -191,26 +191,13 @@ def _csv_rows(rec: dict) -> List[str]:
 
 
 def run() -> List[str]:
-    """Harness entry (benchmarks.run --only topology): spawn the
-    multi-device cell as a subprocess so it gets a fresh jax with the
-    forced device count."""
-    here = Path(__file__).resolve().parents[1]
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count="
-                        + str(HARNESS_DEVICES))
-    env["PYTHONPATH"] = str(here / "src") + os.pathsep \
-        + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.topology_round",
-         "--devices", str(HARNESS_DEVICES)],
-        capture_output=True, text=True, timeout=1800, env=env,
-        cwd=str(here))
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"topology d{HARNESS_DEVICES} cell failed:\n"
-            f"{out.stderr[-2000:]}")
-    return [ln for ln in out.stdout.splitlines()
+    """Harness entry (benchmarks.run --only topology): the multi-device
+    cell in a CPU child process, so it gets a fresh jax with the forced
+    device count."""
+    from benchmarks.common import run_cpu_child
+    out = run_cpu_child(["-m", "benchmarks.topology_round", "--devices",
+                         str(HARNESS_DEVICES)], devices=HARNESS_DEVICES)
+    return [ln for ln in out.splitlines()
             if ln.startswith("topology_round/")]
 
 

@@ -2,13 +2,15 @@
 
 Two layers kill redundant compilation:
 
-  1. **Persistent XLA compilation cache** (cross-process): when
-     ``REPRO_CACHE_DIR`` is set (or a dir is passed explicitly),
-     ``enable_persistent_cache`` points JAX's persistent compilation cache
-     at it with the thresholds dropped to zero, so every jitted program —
+  1. **Persistent XLA compilation cache** (cross-process):
+     ``enable_persistent_cache`` turns on JAX's persistent compilation
+     cache with the thresholds dropped to zero, so every jitted program —
      sweep rounds, figure grids, benchmarks, CI re-runs — compiles once
-     per machine and loads from disk afterwards.  The XLA cache keys on
-     the serialized HLO + compile options + backend, so it is safe across
+     per machine and loads from disk afterwards.  Where
+     ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it and no directory
+     is set in code; otherwise the cache lives at one fixed, git-ignored
+     path in the checkout, ``.jax_cache``.  The XLA cache keys on the
+     serialized HLO + compile options + backend, so it is safe across
      unrelated programs by construction.
 
   2. **In-process program registry** (cross-call): ``get_or_build`` memoizes
@@ -30,11 +32,14 @@ number benchmarks/CI pin to 1 for a mixed-cadence group (BENCH_PR9.json).
 from __future__ import annotations
 
 import os
+from pathlib import Path
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
+from jax.experimental.compilation_cache.compilation_cache import reset_cache
 
-ENV_CACHE_DIR = "REPRO_CACHE_DIR"
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
 
 _persistent_dir: Optional[str] = None
 _REGISTRY: Dict[Any, Any] = {}
@@ -46,39 +51,29 @@ _stats = {"hits": 0, "misses": 0}
 # layer 1: the persistent XLA compilation cache
 # --------------------------------------------------------------------------
 
-def enable_persistent_cache(path: Optional[str] = None) -> Optional[str]:
-    """Wire JAX's persistent compilation cache to ``path`` (default: the
-    ``REPRO_CACHE_DIR`` env var).  Idempotent; returns the active cache dir
-    or None when disabled (env unset and no path given).
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
 
-    Thresholds are dropped to zero so even the small CI/test programs
-    persist — the default min-compile-time gate would skip exactly the
-    programs our warm-start asserts measure.
+    ``JAX_COMPILATION_CACHE_DIR`` where set (JAX reads it itself), else
+    ``DEFAULT_CACHE_DIR``.  Idempotent.  Thresholds are dropped to zero so
+    even the small CI/test programs persist — the default min-compile-time
+    gate would skip exactly the programs the warm-start asserts measure.
     """
     global _persistent_dir
-    target = path if path is not None else os.environ.get(ENV_CACHE_DIR)
-    if not target:
-        return _persistent_dir
-    target = os.path.abspath(target)
+    target = os.environ.get(ENV_CACHE_DIR) or DEFAULT_CACHE_DIR
     if _persistent_dir == target:
-        return _persistent_dir
-    os.makedirs(target, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", target)
+        return target
+    if not os.environ.get(ENV_CACHE_DIR):
+        os.makedirs(target, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", target)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except AttributeError:       # older jax: size gate doesn't exist
-        pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     # jax materializes its cache object once, at the first compile — if
-    # anything compiled before this call (data gen, init_params), the dir
-    # update alone is silently ignored for the rest of the process.
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:            # noqa: BLE001 — private API moved
-        pass
+    # anything compiled before this call (data gen, init_params), the
+    # settings above would be ignored for the rest of the process
+    reset_cache()
     _persistent_dir = target
-    return _persistent_dir
+    return target
 
 
 def persistent_cache_dir() -> Optional[str]:

@@ -324,6 +324,11 @@ def make_streamed_flat_round(cfg: SimConfig, hp: H2FedParams,
             nq = jnp.sum(((w_c > 0) & ~ok).astype(jnp.int32))
             w_c = w_c * ok.astype(jnp.float32)
         num, mass = ops.chunk_agg(stored, w_c, assign_c, R)
+        # the barrier keeps the chunk's partial sum whole: left free, XLA
+        # folds the add into the segment-sum's scatter, (acc + x1) + x2
+        # instead of acc + (x1 + x2), and the round stops matching the
+        # two-axis round's host-side accumulation bit for bit
+        num = jax.lax.optimization_barrier(num)
         return num_acc + num, mass_acc + mass, stored, nq
 
     @jax.jit

@@ -37,8 +37,8 @@ cadence knobs (``lar`` / ``local_epochs`` / ``cloud_every``) batch as
 ONE program — falling back to sequential execution only for the
 tree/sharded/streamed/serve engines, and returns per-scenario histories
 in input order.  Built programs are memoized in the
-``core/program_cache`` registry (and, with ``REPRO_CACHE_DIR`` set, in
-JAX's persistent compilation cache), so re-runs skip tracing/compiling.
+``core/program_cache`` registry (and in JAX's persistent compilation
+cache), so re-runs skip tracing/compiling.
 """
 from __future__ import annotations
 

@@ -14,10 +14,12 @@ would be a segmented reduction; DESIGN.md §2).  The flat-buffer simulation
 engine (DESIGN.md §3) calls this every round via the kernels/ops facade,
 which routes to the equivalent XLA dot off-TPU.
 
-Tiling: A and R are small (≤ a few hundred agents), so W stays fully
-resident in VMEM; the grid walks column blocks of X (the parameter axis,
-potentially billions of elements) and each program computes a
-(R, block_n) = (R, A) @ (A, block_n) tile on the MXU.
+Tiling: W stays fully resident in VMEM; the grid walks column blocks of
+X (the parameter axis, potentially billions of elements) and each program
+computes a (R, block_n) = (R, A) @ (A, block_n) tile on the MXU.  The
+tile width is chosen from A and the dtypes (``_tile_plan``) so the
+double-buffered blocks fit ``VMEM_BUDGET``; a fleet too wide for even one
+128-lane tile is refused, since that needs an A-blocked reduction grid.
 
 One-pass rounds (DESIGN.md §3): the engines' round programs are
 bandwidth-bound on streaming the (A, N)/(R, N) buffers through HBM, so the
@@ -56,17 +58,55 @@ from repro.core.aggregation import (build_weight_matrix, cohort_mass,  # noqa: F
                                     unnormalized_weight_matrix)
 
 LANE = 128
+# VMEM the pipelined blocks of one aggregation call may take: Mosaic's
+# default scoped-VMEM limit (16 MiB on v5e) less 2 MiB for the kernel's
+# own (R, block_n) temporaries (the fp32 buffer tile, the accumulator)
+VMEM_BUDGET = 14 * 2**20
+# the aggregation is a weighted mean: on a TPU an fp32 matmul at default
+# precision takes one bf16 pass, so the fp32 aggregation dots (the
+# kernels' and the XLA route's) ask for fp32 passes (no-op on the CPU)
+FP32 = jax.lax.Precision.HIGHEST
 
 
-def _tile_plan(n: int, block_n: int):
-    """Lane-aligned N-axis tiling: pad N up to the next LANE multiple and
-    clamp the tile to a LANE multiple that divides the padded width.  Every
-    tile is a full-lane tile (no degrade-to-tiny-tiles fallback for awkward
-    N) and the pad waste is bounded by one tile."""
+def _vmem_rows(rows: int, itemsize: int) -> int:
+    """Rows rounded up to the dtype's sublane tile (8 rows of 32-bit
+    words; 16-bit dtypes pack 16 rows, 8-bit 32)."""
+    pack = 8 * (4 // itemsize)
+    return -(-rows // pack) * pack
+
+
+def _tile_plan(n: int, tiled, resident: int = 0, block_n: int = 2048):
+    """Lane-aligned N-axis tiling that fits ``VMEM_BUDGET``.
+
+    ``tiled`` lists ``(rows, itemsize)`` of every block the grid walks
+    column-wise (the X inputs, the previous buffer, the output);
+    ``resident`` is the bytes of the blocks every step reuses (W, coef).
+    N pads up to a LANE multiple and the tile is the widest LANE multiple
+    up to ``block_n`` whose blocks fit: double-buffered when the grid has
+    more than one step, single-buffered when one tile covers N.  The pad
+    waste is bounded by one tile, and the tile never changes how an output
+    column is computed.  Raises ``ValueError`` when even one 128-lane tile
+    does not fit — such a fleet needs an A-blocked reduction grid."""
     lane_n = -(-n // LANE) * LANE
-    bn = max(min(block_n, lane_n) // LANE * LANE, LANE)
+    col = sum(_vmem_rows(r, s) * s for r, s in tiled)
+    if lane_n <= block_n and col * lane_n + resident <= VMEM_BUDGET:
+        return lane_n, lane_n                      # one step, one buffer
+    fit = (VMEM_BUDGET // 2 - resident) // col // LANE * LANE
+    bn = min(max(block_n // LANE * LANE, LANE), fit, lane_n)
+    if bn < LANE:
+        raise ValueError(
+            f"aggregation tile does not fit VMEM: blocks of rows "
+            f"{[r for r, _ in tiled]} need {2 * (col * LANE + resident)} "
+            f"bytes at one {LANE}-lane tile, over the {VMEM_BUDGET}-byte "
+            f"budget; a fleet this wide needs an A-blocked reduction grid")
     n_pad = -(-lane_n // bn) * bn
     return n_pad, bn
+
+
+def _resident_bytes(*shapes) -> int:
+    """VMEM bytes of fp32 blocks that stay resident across the grid."""
+    return sum(_vmem_rows(r, 4) * (-(-c // LANE) * LANE) * 4
+               for r, c in shapes)
 
 
 def _pad_cols(x: jax.Array, n_pad: int) -> jax.Array:
@@ -74,12 +114,20 @@ def _pad_cols(x: jax.Array, n_pad: int) -> jax.Array:
     return jnp.pad(x, ((0, 0), (0, pad))) if pad else x
 
 
+def _contract(w, x):
+    """(R, A) @ (A, BN) accumulated in fp32, in the fleet's dtype: an fp32
+    fleet contracts at fp32 precision, a narrower one reads X as stored
+    and rounds the small weight matrix to it — the XLA route's contract
+    (``kernels/ops._xla_agg_matmul``)."""
+    f32 = jnp.dtype(x.dtype) == jnp.dtype(jnp.float32)
+    return jax.lax.dot_general(
+        w.astype(x.dtype), x, (((1,), (0,)), ((), ())),
+        precision=FP32 if f32 else None,
+        preferred_element_type=jnp.float32)
+
+
 def _agg_kernel(w_ref, x_ref, o_ref):
-    w = w_ref[...].astype(jnp.float32)            # (R, A)
-    x = x_ref[...].astype(jnp.float32)            # (A, BN)
-    o_ref[...] = jax.lax.dot_general(
-        w, x, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+    o_ref[...] = _contract(w_ref[...], x_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -91,7 +139,9 @@ def weighted_agg_matmul(weight_matrix: jax.Array, stacked: jax.Array, *,
     R, A = weight_matrix.shape
     A2, N = stacked.shape
     assert A == A2, (A, A2)
-    n_pad, block_n = _tile_plan(N, block_n)
+    n_pad, block_n = _tile_plan(
+        N, [(A, stacked.dtype.itemsize), (R, stacked.dtype.itemsize)],
+        _resident_bytes((R, A)), block_n)
     xs = _pad_cols(stacked, n_pad)
     grid = (n_pad // block_n,)
 
@@ -184,11 +234,8 @@ def _make_fused_kernel(n_pairs: int):
         o_ref = refs[2 + 2 * n_pairs]
         acc = coef[:, 0:1] * buf                           # retained·buf
         for i in range(n_pairs):
-            w = refs[1 + 2 * i][...].astype(jnp.float32)   # (R, A_i)
-            x = refs[2 + 2 * i][...].astype(jnp.float32)   # (A_i, BN)
-            acc += jax.lax.dot_general(
-                w, x, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            acc += _contract(refs[1 + 2 * i][...],         # (R, A_i)
+                             refs[2 + 2 * i][...])         # (A_i, BN)
         merged = acc / coef[:, 1:2]                        # / safe
         o_ref[...] = jnp.where(coef[:, 2:3] > 0, merged,
                                buf).astype(o_ref.dtype)
@@ -205,7 +252,12 @@ def _fused_agg_blend(coef: jax.Array, weight_mats, stackeds,
     buffer) is read once and the output tile written once.  coef: (R, 3)
     rows of [retained, safe, guard]; out dtype == buf dtype."""
     R, N = buf.shape
-    n_pad, block_n = _tile_plan(N, block_n)
+    n_pad, block_n = _tile_plan(
+        N,
+        [(x.shape[0], x.dtype.itemsize) for x in stackeds]
+        + [(R, buf.dtype.itemsize)] * 2,                       # buf + out
+        _resident_bytes((R, 3), *(w.shape for w in weight_mats)),
+        block_n)
     kernel = _make_fused_kernel(len(weight_mats))
 
     in_specs = [pl.BlockSpec((R, 3), lambda i: (0, 0))]    # coef resident

@@ -71,7 +71,8 @@ def _xla_agg_matmul(weight_matrix, stacked):
     policy's HBM savings); fp32 fleets are unchanged bit-for-bit."""
     out = jax.lax.dot_general(
         weight_matrix.astype(stacked.dtype), stacked,
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        (((1,), (0,)), ((), ())), precision=_mha.FP32,
+        preferred_element_type=jnp.float32)
     return out.astype(stacked.dtype)
 
 
@@ -241,7 +242,7 @@ def cloud_blend(rsu_flat, rsu_weights, prev):
         wn, _ = normalized_weights(rsu_weights)
         new = jax.lax.dot_general(
             wn[None, :], rsu_flat.astype(jnp.float32),
-            (((1,), (0,)), ((), ())),
+            (((1,), (0,)), ((), ())), precision=_mha.FP32,
             preferred_element_type=jnp.float32)[0]
         return jnp.where(total > 0, new,
                          prev.astype(jnp.float32)).astype(prev.dtype)

@@ -76,8 +76,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):     # old-jax: one dict per device
-        cost = cost[0]
     hlo = compiled.as_text()
     # trip-count-aware per-device analysis (XLA counts scan bodies once)
     an = hlo_analysis.analyze(hlo)

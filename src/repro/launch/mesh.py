@@ -14,35 +14,18 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions.
-
-    ``axis_types`` (explicit Auto axes) only exists on newer jax; on the
-    0.4.x line every mesh axis is Auto already, so omitting it is exact.
-    """
-    shape, axes = tuple(shape), tuple(axes)
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis explicitly ``Auto``."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names, check: bool = False):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax takes ``axis_names`` (manual axes) + ``check_vma``; the 0.4.x
-    ``jax.experimental.shard_map`` expresses the same contract as
-    ``auto`` (the complement set) + ``check_rep``.
-    """
-    axis_names = frozenset(axis_names)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset(mesh.axis_names) - axis_names
-    return _shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check, auto=auto)
+    """``jax.shard_map``, manual over ``axis_names`` (the rest stay auto);
+    ``check`` is its ``check_vma``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         axis_names=frozenset(axis_names), check_vma=check)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

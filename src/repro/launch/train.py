@@ -1,15 +1,16 @@
 """Production training launcher: H²-Fed hierarchical rounds on a device mesh.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b \
-        [--devices 8 --mesh 2,4,1] [--reduced] [--rounds 8] \
+        [--mesh 2,4,1] [--devices 8] [--reduced] [--rounds 8] \
         [--lar 4] [--epochs 1] [--csr 0.8] [--quantize-cloud] \
         [--adaptive-mu] [--ckpt-dir results/ckpt] [--seq 128 --batch 4]
 
 Runs the paper's Algorithms 1–3 as one compiled SPMD program per global
 round (launch/h2fed_round.py) over synthetic Non-IID LM shards, with
 checkpointing and optional adaptive-mu orchestration (core/orchestrator).
-On CPU pass --devices to materialize host devices; on a real TPU slice the
-flag is unnecessary and --mesh should match the topology.
+The mesh defaults to the visible devices (2 pods when there are 4 or
+more, an even count).  ``--devices N`` is for CPU dry runs only: it forces
+N host devices before JAX starts.
 
 ``--scenario-json spec.json`` instead runs a declarative experiment
 scenario (core/scenario.ScenarioSpec, DESIGN.md §7) through the fedsim
@@ -32,10 +33,13 @@ def _parse_args():
     ap.add_argument("--reduced", action="store_true", default=True,
                     help="reduced config (full configs need a real pod)")
     ap.add_argument("--full-config", dest="reduced", action="store_false")
-    ap.add_argument("--devices", type=int, default=8,
-                    help="host device count (CPU dry runs)")
-    ap.add_argument("--mesh", default="2,4,1",
-                    help="pod,data,model mesh shape")
+    ap.add_argument("--devices", type=int, default=0,
+                    help="force this many host devices (CPU dry runs; "
+                         "0 = the visible devices)")
+    ap.add_argument("--mesh", default="",
+                    help="pod,data,model mesh shape (default: the visible "
+                         "devices as 2 x n/2 x 1 for an even n >= 4, else "
+                         "1 x n x 1)")
     ap.add_argument("--rounds", type=int, default=4)
     ap.add_argument("--lar", type=int, default=4)
     ap.add_argument("--epochs", type=int, default=1)
@@ -153,7 +157,11 @@ def main():
 
     from repro.launch.mesh import make_mesh
 
-    mesh_shape = tuple(int(x) for x in args.mesh.split(","))
+    if args.mesh:
+        mesh_shape = tuple(int(x) for x in args.mesh.split(","))
+    else:
+        n = len(jax.devices())
+        mesh_shape = (2, n // 2, 1) if n >= 4 and n % 2 == 0 else (1, n, 1)
     mesh = make_mesh(mesh_shape, ("pod", "data", "model"))
     topo = HierarchyTopology.from_mesh(mesh)
     A = topo.n_agents

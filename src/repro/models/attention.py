@@ -32,21 +32,12 @@ def maybe_constrain(x, *spec):
     if pm.empty:
         return x
     # inside shard_map some axes are Manual — the constraint may only name
-    # Auto axes (the abstract mesh carries the per-trace axis types).  Older
-    # jax has no abstract mesh and its axis env can't tell Manual from Auto,
-    # so there the hint is skipped whenever any named axis is in scope (the
-    # constraint is an optimization, never a semantics change).
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
+    # Auto axes (the abstract mesh carries the per-trace axis types)
     auto = set(pm.axis_names)
-    if get_am is not None:
-        am = get_am()
-        if am is not None and not am.empty:
-            auto = {a for a in am.axis_names
-                    if am._name_to_type[a] == jax.sharding.AxisType.Auto}
-    else:
-        from jax._src import core as _core
-        if getattr(_core.get_axis_env(), "axis_sizes", None):
-            return x
+    am = jax.sharding.get_abstract_mesh()
+    if am is not None and not am.empty:
+        auto = {a for a in am.axis_names
+                if am._name_to_type[a] == jax.sharding.AxisType.Auto}
     fixed = []
     for dim, ax in zip(x.shape, spec):
         if (ax is None or ax not in pm.axis_names or ax not in auto

@@ -17,6 +17,10 @@ import numpy as np
 import pytest
 
 jax.config.update("jax_enable_x64", False)
+# the suite compiles hundreds of small programs across parallel workers;
+# keep them out of the persistent compilation cache (tests/
+# test_program_cache.py exercises the cache in child processes)
+jax.config.update("jax_enable_compilation_cache", False)
 
 SRC = str(Path(__file__).resolve().parents[1] / "src")
 
@@ -32,6 +36,7 @@ def run_forced_devices(code: str, devices: int = 8,
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, timeout=timeout,
                          env=env)

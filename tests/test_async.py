@@ -170,7 +170,7 @@ class TestSyncLimit:
         assert float(jnp.sum(sa.pending_w)) == 0.0       # nothing in flight
 
     @settings(max_examples=2, deadline=None)
-    @given(seed=st.integers(0, 100), csr=st.floats(0.2, 1.0, width=32))
+    @given(seed=st.integers(0, 100), csr=st.floats(0.2, 1.0))
     def test_sync_limit_property(self, small_fed, seed, csr):
         from repro.core.baselines import h2fed
         from repro.fedsim.async_engine import AsyncConfig

@@ -245,9 +245,36 @@ def test_weighted_agg_matmul_ragged_n(N):
     got_ops = ops.weighted_agg_matmul(W, x)        # XLA route on CPU
     np.testing.assert_allclose(np.asarray(got_ops), exp, atol=2e-5,
                                rtol=2e-5)
-    n_pad, bn = _tile_plan(N, 2048)
+    n_pad, bn = _tile_plan(N, [(A, 4), (R, 4)])
     assert bn % 128 == 0 and n_pad % bn == 0 and n_pad >= N
     assert n_pad - N < bn + 128                    # bounded pad waste
+
+
+@pytest.mark.parametrize("A,itemsize", [(100, 4), (2000, 4), (2000, 2),
+                                        (8192, 2)])
+def test_tile_plan_fits_vmem(A, itemsize):
+    """The N tile shrinks with the fleet width and dtype so the pipelined
+    blocks stay inside VMEM_BUDGET (double-buffered across a multi-step
+    grid, single-buffered when one tile covers N)."""
+    from repro.kernels.masked_hier_agg import (LANE, VMEM_BUDGET,
+                                               _tile_plan, _vmem_rows)
+    R = 10
+    for N in (68, 31_810, 9_540_000):
+        n_pad, bn = _tile_plan(N, [(A, itemsize), (R, 4), (R, 4)])
+        assert bn % LANE == 0 and n_pad % bn == 0 and n_pad >= N
+        bufs = 1 if n_pad == bn else 2
+        col = _vmem_rows(A, itemsize) * itemsize + 2 * _vmem_rows(R, 4) * 4
+        assert bufs * col * bn <= VMEM_BUDGET
+    # the narrow paper fleet keeps the full default tile
+    assert _tile_plan(31_810, [(100, 4), (R, 4), (R, 4)]) == (32_768, 2048)
+
+
+def test_tile_plan_refuses_past_one_lane_tile():
+    from repro.kernels.masked_hier_agg import _tile_plan
+    with pytest.raises(ValueError, match="A-blocked reduction grid"):
+        _tile_plan(31_810, [(20_000, 4), (10, 4), (10, 4)])
+    # a one-step grid is single-buffered, so the same rows fit at N = 68
+    assert _tile_plan(68, [(20_000, 4), (10, 4), (10, 4)]) == (128, 128)
 
 
 # --------------------------------------------------------------------------

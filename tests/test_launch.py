@@ -11,18 +11,6 @@ import pytest
 
 from conftest import run_forced_devices as _run_sub
 
-# jaxlib < 0.5 hard-aborts (Check failed: sharding.IsManualSubgroup()) when
-# the SPMD partitioner meets the transformer h2fed_round's manual(pod,data) x
-# auto(model) subgroup program.  The MLP-fleet sharded engine (test_sharded)
-# and the model-axis-1 CLI path are unaffected; on jax >= 0.5 these run.
-import jax  # noqa: E402
-
-OLD_JAX_SPMD = tuple(
-    int(x) for x in jax.__version__.split(".")[:2]) < (0, 5)
-needs_spmd_subgroups = pytest.mark.skipif(
-    OLD_JAX_SPMD, reason="manual x auto shard_map subgroups crash the XLA "
-                         "SPMD partitioner on jaxlib < 0.5")
-
 
 class TestMesh:
     def test_mesh_shapes(self):
@@ -53,7 +41,6 @@ class TestMesh:
 
 
 class TestH2FedRoundShardMap:
-    @needs_spmd_subgroups
     def test_round_matches_fedsim_semantics(self):
         """The compiled shard_map hierarchical round must be numerically
         equivalent to a replicated-math reference of Algorithms 1-3 (same
@@ -144,9 +131,8 @@ class TestH2FedRoundShardMap:
 
     def test_flat_agg_matches_per_leaf(self):
         """flat_agg=True (one raveled-buffer collective per layer) must be
-        numerically identical to the per-leaf reductions.  model-axis size 1
-        so the program runs on every supported jax (see needs_spmd_subgroups
-        for the TP>1 regime)."""
+        numerically identical to the per-leaf reductions (model-axis size
+        1)."""
         code = """
         import jax, jax.numpy as jnp, numpy as np
         from repro.launch.mesh import make_test_mesh
@@ -196,7 +182,6 @@ class TestH2FedRoundShardMap:
         out = _run_sub(code, devices=8, timeout=900)
         assert "flat-agg ok" in out
 
-    @needs_spmd_subgroups
     def test_quantized_cloud_agg_close_to_exact(self):
         """int8 cross-pod aggregation stays within quantization error."""
         code = """
@@ -258,7 +243,6 @@ class TestDryRunMini:
                 .lower(*spec['args'])
             compiled = lowered.compile()
         ca = compiled.cost_analysis()
-        ca = ca[0] if isinstance(ca, (list, tuple)) else ca   # old-jax: list
         assert ca['flops'] > 0
         txt = compiled.as_text()
         assert 'all-reduce' in txt or 'all-gather' in txt
@@ -281,10 +265,7 @@ class TestDryRunMini:
             compiled = jax.jit(spec['fn'], in_shardings=spec['in_shardings']) \\
                 .lower(*spec['args']).compile()
         mem = compiled.memory_analysis()
-        peak = getattr(mem, 'peak_memory_in_bytes', None)
-        if peak is None:                      # old-jax: no peak stat
-            peak = mem.temp_size_in_bytes + mem.output_size_in_bytes
-        assert peak > 0
+        assert mem.peak_memory_in_bytes > 0
         print('ok')
         """
         assert "ok" in _run_sub(code, devices=8, timeout=900)

@@ -19,9 +19,9 @@ def _run(args, timeout=480):
 
 def test_train_then_serve_roundtrip(tmp_path):
     ck = str(tmp_path / "ckpt")
-    out = _run(["repro.launch.train", "--rounds", "2", "--lar", "2",
-                "--seq", "64", "--batch", "2", "--ckpt-every", "2",
-                "--ckpt-dir", ck])
+    out = _run(["repro.launch.train", "--devices", "8", "--rounds", "2",
+                "--lar", "2", "--seq", "64", "--batch", "2",
+                "--ckpt-every", "2", "--ckpt-dir", ck])
     assert out.returncode == 0, out.stderr[-3000:]
     assert "[done]" in out.stdout
     assert "[ckpt]" in out.stdout
@@ -36,17 +36,17 @@ def test_train_then_serve_roundtrip(tmp_path):
 def test_train_async_rounds_flag():
     """--async-rounds drives the semi-async SPMD path (DESIGN.md §6) and
     auto-enables flat_agg for the raveled pending buffer."""
-    out = _run(["repro.launch.train", "--rounds", "2", "--lar", "2",
-                "--seq", "32", "--batch", "2", "--async-rounds", "2",
-                "--csr", "0.5"])
+    out = _run(["repro.launch.train", "--devices", "8", "--rounds", "2",
+                "--lar", "2", "--seq", "32", "--batch", "2",
+                "--async-rounds", "2", "--csr", "0.5"])
     assert out.returncode == 0, out.stderr[-3000:]
     assert "[done]" in out.stdout
     assert "implies --flat-agg" in out.stdout
 
 
 def test_train_adaptive_mu_flag(tmp_path):
-    out = _run(["repro.launch.train", "--rounds", "2", "--lar", "1",
-                "--seq", "32", "--batch", "2", "--csr", "0.3",
+    out = _run(["repro.launch.train", "--devices", "8", "--rounds", "2",
+                "--lar", "1", "--seq", "32", "--batch", "2", "--csr", "0.3",
                 "--adaptive-mu"])
     assert out.returncode == 0, out.stderr[-3000:]
     # the controller must have moved mu away from the base once csr_obs

@@ -7,6 +7,7 @@ import os
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -129,34 +130,51 @@ class TestRegistry:
         assert program_cache.trace_count("x") == 0
 
 
+ROOT = Path(__file__).resolve().parents[1]
+
+CACHE_CODE = textwrap.dedent("""
+    import jax, jax.numpy as jnp
+    from repro.core import program_cache
+    d = program_cache.enable_persistent_cache()
+    x = jax.jit(lambda v: (v * 2.0 + 1.0).sum())(jnp.ones((8, 8)))
+    x.block_until_ready()
+    print("PERSIST", d, jax.config.jax_compilation_cache_dir)
+""")
+
+
+def _cache_child(env_dir=None):
+    env = dict(os.environ,
+               PYTHONPATH=str(ROOT / "src") + os.pathsep + os.environ.get(
+                   "PYTHONPATH", ""))
+    env.pop(program_cache.ENV_CACHE_DIR, None)
+    if env_dir is not None:
+        env[program_cache.ENV_CACHE_DIR] = str(env_dir)
+    out = subprocess.run([sys.executable, "-c", CACHE_CODE], cwd=ROOT,
+                         env=env, capture_output=True, text=True)
+    assert out.returncode == 0, out.stderr
+    line = [ln for ln in out.stdout.splitlines() if ln.startswith("PERSIST")]
+    return line[-1].split()[1:]
+
+
 class TestPersistentCache:
     def test_enable_persistent_cache_writes_entries(self, tmp_path):
-        """Fresh process (config flags are process-global): enabling the
-        cache and running a jitted program must land entries on disk, and
-        a second process must load them (the cold/warm contract CI pins)."""
+        """Fresh processes (config flags are process-global): with
+        ``JAX_COMPILATION_CACHE_DIR`` set, the cache lives there and only
+        there — no directory is set over it — and a jitted program lands
+        entries on disk that a second process loads (the cold/warm
+        contract CI pins)."""
         cache = tmp_path / "xla-cache"
-        code = textwrap.dedent("""
-            import sys
-            import jax, jax.numpy as jnp
-            from repro.core import program_cache
-            d = program_cache.enable_persistent_cache(sys.argv[1])
-            assert d is not None
-            x = jax.jit(lambda v: (v * 2.0 + 1.0).sum())(jnp.ones((8, 8)))
-            x.block_until_ready()
-            print("PERSIST_OK")
-        """)
-        env = dict(os.environ,
-                   PYTHONPATH="src" + os.pathsep + os.environ.get(
-                       "PYTHONPATH", ""))
         for _ in range(2):      # cold run writes, warm run reads
-            out = subprocess.run(
-                [sys.executable, "-c", code, str(cache)],
-                cwd="/root/repo", env=env, capture_output=True, text=True)
-            assert out.returncode == 0, out.stderr
-            assert "PERSIST_OK" in out.stdout
+            active, jax_dir = _cache_child(cache)
+            assert active == jax_dir == str(cache)
             assert any(cache.iterdir()), "no cache entries written"
 
-    def test_env_var_unset_is_noop(self, monkeypatch):
-        monkeypatch.delenv(program_cache.ENV_CACHE_DIR, raising=False)
-        before = program_cache.persistent_cache_dir()
-        assert program_cache.enable_persistent_cache() == before
+    def test_env_var_unset_is_noop(self):
+        """Unset, the variable adds nothing: the cache goes to one fixed,
+        git-ignored path inside the checkout, so every process of the
+        checkout shares it."""
+        active, jax_dir = _cache_child()
+        assert active == jax_dir == program_cache.DEFAULT_CACHE_DIR
+        default = Path(program_cache.DEFAULT_CACHE_DIR)
+        assert default.parent == ROOT
+        assert f"{default.name}/" in (ROOT / ".gitignore").read_text()
