@@ -28,6 +28,15 @@ Two layers kill redundant compilation:
 Trace accounting: round bodies call :func:`note_trace` from inside their
 Python trace, so ``trace_count(label)`` counts actual (re)traces — the
 number benchmarks/CI pin to 1 for a mixed-cadence group (BENCH_PR9.json).
+
+Compile accounting: from the first ``run_scenario`` or
+``enable_persistent_cache`` call on, JAX's monitoring events feed
+process-wide seconds and event counts of every jitted call's Python trace
+(``trace_s``), its lowering to MLIR (``lower_s``), its backend compile
+(``compile_s``; on a persistent-cache hit, the load) and the cache read
+inside it (``cache_load_s``).  A jit traced inside another's trace
+records its own event within the outer one, so ``trace_s`` counts nested
+traces twice.
 """
 from __future__ import annotations
 
@@ -45,6 +54,15 @@ _persistent_dir: Optional[str] = None
 _REGISTRY: Dict[Any, Any] = {}
 _TRACES: Dict[str, int] = {}
 _stats = {"hits": 0, "misses": 0}
+# JAX monitoring event -> the counter it feeds
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+_compile: Dict[str, float] = {}
+_watching = False
 
 
 # --------------------------------------------------------------------------
@@ -60,6 +78,7 @@ def enable_persistent_cache() -> str:
     gate would skip exactly the programs the warm-start asserts measure.
     """
     global _persistent_dir
+    watch_compiles()
     target = os.environ.get(ENV_CACHE_DIR) or DEFAULT_CACHE_DIR
     if _persistent_dir == target:
         return target
@@ -79,6 +98,31 @@ def enable_persistent_cache() -> str:
 def persistent_cache_dir() -> Optional[str]:
     """The active persistent-cache dir (None = disabled)."""
     return _persistent_dir
+
+
+def _zero_compile_counters() -> None:
+    for name in COMPILE_EVENTS.values():
+        _compile[f"{name}_s"] = 0.0
+        _compile[f"{name}_events"] = 0
+
+
+def _on_duration(event: str, duration: float, **_) -> None:
+    name = COMPILE_EVENTS.get(event)
+    if name is not None:
+        _compile[f"{name}_s"] += duration
+        _compile[f"{name}_events"] += 1
+
+
+def watch_compiles() -> None:
+    """Register the listener that feeds the compile counters (once per
+    process; ``run_scenario`` and ``enable_persistent_cache`` call it)."""
+    global _watching
+    if not _watching:
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _watching = True
+
+
+_zero_compile_counters()
 
 
 # --------------------------------------------------------------------------
@@ -148,15 +192,17 @@ def trace_count(label: str) -> int:
     return _TRACES.get(label, 0)
 
 
-def stats() -> Dict[str, int]:
-    return dict(_stats, entries=len(_REGISTRY), **{
+def stats() -> Dict[str, float]:
+    return dict(_stats, entries=len(_REGISTRY), **_compile, **{
         f"traces/{k}": v for k, v in _TRACES.items()})
 
 
 def reset_stats() -> None:
-    """Zero the hit/miss/trace counters (the registry itself survives)."""
+    """Zero the hit/miss/trace and compile counters (the registry itself
+    survives)."""
     _stats["hits"] = _stats["misses"] = 0
     _TRACES.clear()
+    _zero_compile_counters()
 
 
 def clear() -> None:

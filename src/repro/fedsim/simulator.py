@@ -165,6 +165,7 @@ def _epoch_cap(local_epochs):
     return jnp.maximum(local_epochs, 1)
 
 
+@jax.named_scope("h2fed.draws")
 def round_draws(key, conn: ConnState, het: HeterogeneityModel,
                 hp: H2FedParams, n_agents: int, spe: int):
     """One local round's stochastic realization, shared by every engine.
@@ -214,6 +215,7 @@ def _local_train(loss_fn: Callable, x, y, w0: PyTree, w_rsu: PyTree,
     return w
 
 
+@jax.named_scope("h2fed.local_train")
 def _local_train_flat(loss_fn: Callable, spec: flatten.FlatSpec, x, y,
                       w0: jax.Array, w_rsu: jax.Array, w_cloud: jax.Array,
                       hp: H2FedParams, n_steps: int,
@@ -326,10 +328,11 @@ def _make_flat_round_body(cfg: SimConfig, hp: H2FedParams,
             maskf = mask.astype(jnp.float32)
 
             # Alg. 2 l.5 / Alg. 1 l.1: every agent starts from its RSU row
-            w_start = jnp.take(rsu_prev, rsu_assign, axis=0)     # (A, N)
-            agent_flat = spec.to_storage(
-                train_agents(x_all, y_all, w_start, w_start,
-                             state.cloud_flat, active_steps))
+            with jax.named_scope("h2fed.local_train"):
+                w_start = jnp.take(rsu_prev, rsu_assign, axis=0)  # (A, N)
+                agent_flat = spec.to_storage(
+                    train_agents(x_all, y_all, w_start, w_start,
+                                 state.cloud_flat, active_steps))
 
             nq = None
             if faults is not None:
@@ -555,28 +558,30 @@ def _run_sync(res, init_params: PyTree, *,
         x_test, y_test = jnp.asarray(x_test), jnp.asarray(y_test)
         eval_fn = jax.jit(lambda p: mlp.accuracy(p, x_test, y_test))
 
-    if engine == "flat":
-        spec = flatten.spec_of(
-            init_params,
-            storage_dtype=flatten.resolve_storage_dtype(fleet_dtype))
-        state = init_flat_state(cfg, spec, init_params, key)
-        round_fn = make_flat_global_round(cfg, hp, het, fed, spec, loss_fn,
-                                          fused=fused, faults=s.faults)
-        # eval_fn is called eagerly (unravel is cheap outside jit) so
-        # user-supplied non-traceable metrics keep working; the built-in
-        # accuracy eval_fn above is already jitted.
-        eval_state = (None if eval_fn is None else
-                      (lambda s: eval_fn(spec.unravel(s.cloud_flat))))
-        finalize = lambda s: from_flat_state(spec, s)        # noqa: E731
-    elif engine == "tree":
-        state = init_state(cfg, init_params, key)
-        round_fn = _make_tree_global_round(cfg, hp, het, fed, loss_fn)
-        eval_state = (None if eval_fn is None else
-                      (lambda s: eval_fn(s.cloud_params)))
-        finalize = lambda s: s                               # noqa: E731
-    else:
-        raise ValueError(
-            f"unknown engine {engine!r} (want 'flat'|'tree'|'async')")
+    with jax.profiler.TraceAnnotation("h2fed.build"):
+        if engine == "flat":
+            spec = flatten.spec_of(
+                init_params,
+                storage_dtype=flatten.resolve_storage_dtype(fleet_dtype))
+            state = init_flat_state(cfg, spec, init_params, key)
+            round_fn = make_flat_global_round(cfg, hp, het, fed, spec,
+                                              loss_fn, fused=fused,
+                                              faults=s.faults)
+            # eval_fn is called eagerly (unravel is cheap outside jit) so
+            # user-supplied non-traceable metrics keep working; the
+            # built-in accuracy eval_fn above is already jitted.
+            eval_state = (None if eval_fn is None else
+                          (lambda s: eval_fn(spec.unravel(s.cloud_flat))))
+            finalize = lambda s: from_flat_state(spec, s)    # noqa: E731
+        elif engine == "tree":
+            state = init_state(cfg, init_params, key)
+            round_fn = _make_tree_global_round(cfg, hp, het, fed, loss_fn)
+            eval_state = (None if eval_fn is None else
+                          (lambda s: eval_fn(s.cloud_params)))
+            finalize = lambda s: s                           # noqa: E731
+        else:
+            raise ValueError(
+                f"unknown engine {engine!r} (want 'flat'|'tree'|'async')")
 
     # fault schedules lower once per run to per-tick mask data over the
     # global tick clock (rounds x lar); each round consumes its slice
@@ -584,16 +589,21 @@ def _run_sync(res, init_params: PyTree, *,
     if s.faults is not None and engine == "flat":
         sched = s.faults.lower(cfg.n_agents, cfg.n_rsus, n_rounds * hp.lar)
 
+    # host spans on the profiler's clock: each round's dispatch (the
+    # first carries the round's trace, lowering and cache load) and its
+    # eval with the readback, both tagged with the round index
     accs, rounds, quarantined = [], [], []
     for r in range(n_rounds):
-        if sched is None:
-            state = round_fn(state)
-        else:
-            state, fm = round_fn(state, sched.round_slice(r, hp.lar))
-            quarantined.append(int(fm["quarantined"]))
+        with jax.profiler.TraceAnnotation("h2fed.round", round=r):
+            if sched is None:
+                state = round_fn(state)
+            else:
+                state, fm = round_fn(state, sched.round_slice(r, hp.lar))
+                quarantined.append(int(fm["quarantined"]))
         if eval_state is not None and (r % cfg.eval_every == 0
                                        or r == n_rounds - 1):
-            accs.append(float(eval_state(state)))
+            with jax.profiler.TraceAnnotation("h2fed.eval", round=r):
+                accs.append(float(eval_state(state)))
             rounds.append(r + 1)
     history = {"round": np.asarray(rounds), "acc": np.asarray(accs)}
     if sched is not None:

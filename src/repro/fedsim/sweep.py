@@ -106,6 +106,7 @@ def run_scenario(res, init_params: Optional[PyTree] = None, *,
     if isinstance(res, ScenarioSpec):
         res = res.resolve()
     s = res.spec.validate()
+    program_cache.watch_compiles()
     if s.program_cache:
         program_cache.enable_persistent_cache()
     if init_params is None:

@@ -171,7 +171,11 @@ def cloud_agg(rsu_flat, rsu_weights):
 # --------------------------------------------------------------------------
 # fused aggregate-and-blend entry points (one-pass rounds, DESIGN.md §3/§6)
 # --------------------------------------------------------------------------
+# Both layers launch the same kernel; the named scopes (h2fed.rsu_agg,
+# h2fed.cloud_blend) tell the launches, and their pads and slices, apart
+# in a profile.
 
+@jax.named_scope("h2fed.rsu_agg")
 def agg_blend(stacked_flat, weights, mask, rsu_assign, n_rsus: int, prev):
     """Fused RSU aggregation + mass-guard blend:
     ``out[r] = where(mass[r] > 0, W_norm[r] @ X, prev[r])`` with each
@@ -192,6 +196,7 @@ def agg_blend(stacked_flat, weights, mask, rsu_assign, n_rsus: int, prev):
                           prev, interpret=False)
 
 
+@jax.named_scope("h2fed.rsu_agg")
 def agg_absorb(arrivals, rsu_assign, n_rsus: int, buf, buf_mass, *,
                keep=0.0):
     """Fused multi-cohort scatter-accumulate + staleness-buffer merge
@@ -231,6 +236,7 @@ def agg_absorb(arrivals, rsu_assign, n_rsus: int, buf, buf_mass, *,
                            keep=keep, interpret=False)
 
 
+@jax.named_scope("h2fed.cloud_blend")
 def cloud_blend(rsu_flat, rsu_weights, prev):
     """Fused cloud aggregation + keep-guard:
     ``where(Σ mass > 0, wn @ rsu_flat, prev)`` in one pass; out dtype
