@@ -4,11 +4,10 @@ The simulator's hot path treats the fleet as matrices, not pytrees: every
 agent's parameters are raveled into one contiguous fp32 row of an ``(A, N)``
 buffer (RSUs: ``(R, N)``; cloud: ``(N,)``), so hierarchical aggregation is a
 single ``(R, A) @ (A, N)`` Pallas matmul (kernels/masked_hier_agg) instead of
-O(leaves) tree-mapped reductions, and the dual-proximal SGD update is one
-fused vector expression.  Structure round-trips losslessly: ravel/unravel are
-pure reshape+concatenate/slice, bit-exact for matching dtypes, and
-differentiable — ``jax.grad`` of a loss composed with ``unravel`` yields the
-raveled gradient directly.
+O(leaves) tree-mapped reductions.  Structure round-trips losslessly:
+ravel/unravel are pure reshape+concatenate/slice, bit-exact for matching
+dtypes, and differentiable — ``jax.grad`` of a loss composed with
+``unravel`` yields the raveled gradient directly.
 
 A ``FlatSpec`` is static metadata (treedef + leaf shapes/dtypes/offsets)
 derived once per simulation from the parameter template; it never crosses a

@@ -18,10 +18,10 @@ Two engines share this program structure (DESIGN.md §3):
   engine="flat" (default, the production hot path) — the fleet lives in
   contiguous fp32 buffers: agents (A, N), RSUs (R, N), cloud (N,)
   (core/flatten).  Both aggregation layers are single Pallas matmul calls
-  (kernels/masked_hier_agg via kernels/ops) and the dual-proximal update is
-  one fused vector expression; parameters are unraveled to pytrees only at
-  eval/checkpoint boundaries.  fedsim/sharded.py partitions the same
-  buffers' agent axis over a device mesh.
+  (kernels/masked_hier_agg via kernels/ops); local training unravels each
+  agent's row once and runs the dual-proximal update on the model's leaves.
+  fedsim/sharded.py partitions the same buffers' agent axis over a device
+  mesh.
 
   engine="tree" (the reference) — per-leaf jax.tree.map aggregation
   (core/aggregation).  Property tests assert both engines agree to fp32
@@ -220,29 +220,36 @@ def _local_train_flat(loss_fn: Callable, spec: flatten.FlatSpec, x, y,
                       w0: jax.Array, w_rsu: jax.Array, w_cloud: jax.Array,
                       hp: H2FedParams, n_steps: int,
                       active_steps: jax.Array, batch: int) -> jax.Array:
-    """Flat-buffer twin of ``_local_train``: the whole model is one (N,)
-    fp32 vector, so the dual-proximal update (Alg. 1, Eq. 6) is a single
-    fused expression — no per-leaf tree traffic in the inner loop.
+    """``_local_train`` on one agent's flat (N,) row: the rows are
+    unraveled once at entry, the minibatch scan carries the model's
+    leaves, and the result is raveled once at exit.
+
+    The fleet buffers stay flat for aggregation, but a flat scan carry
+    made every step unravel the weights and ravel the gradient back: on a
+    TPU those relayout copies cost more than the products they fed
+    (DESIGN.md §3).
 
     Compute is always fp32: storage-dtype (bf16) inputs are widened at
-    entry (a no-op under the fp32 default), so training precision is
-    independent of the fleet-buffer storage dtype; the caller casts the
-    returned fp32 vector back into storage when writing the buffer."""
+    entry (a no-op under the fp32 default) and the carry holds fp32
+    leaves, so training precision is independent of the fleet-buffer
+    storage dtype; ``loss_fn`` sees each leaf in the template's dtype, as
+    ``spec.unravel`` gives it.  The caller casts the returned fp32 vector
+    back into storage when writing the buffer."""
+    f32 = dataclasses.replace(spec, dtypes=(jnp.float32,) * len(spec.dtypes))
+    dtypes = jax.tree_util.tree_unflatten(spec.treedef, spec.dtypes)
 
-    grad_fn = jax.grad(lambda wf, xb, yb: loss_fn(spec.unravel(wf), xb, yb))
-    w_rsu = w_rsu.astype(jnp.float32)
-    w_cloud = w_cloud.astype(jnp.float32)
+    def template_loss(w, xb, yb):
+        return loss_fn(jax.tree.map(lambda l, d: l.astype(d), w, dtypes),
+                       xb, yb)
 
-    def body(w, step):
-        xb, yb = agent_minibatch(x, y, step, batch)
-        g = grad_fn(w, xb, yb)
-        live = (step < active_steps).astype(jnp.float32)
-        w = w - hp.lr * live * (g + hp.mu1 * (w - w_rsu)
-                                + hp.mu2 * (w - w_cloud))
-        return w, None
-
-    w, _ = jax.lax.scan(body, w0.astype(jnp.float32), jnp.arange(n_steps))
-    return w
+    w0 = w0.astype(jnp.float32)
+    trained = _local_train(template_loss, x, y, f32.unravel(w0),
+                           f32.unravel(w_rsu.astype(jnp.float32)),
+                           f32.unravel(w_cloud.astype(jnp.float32)),
+                           hp, n_steps, active_steps, batch)
+    # columns past spec.n pad the parameter axis of N-sharded and N-tiled
+    # rows: no leaf reads them, so they pass through unchanged
+    return jnp.concatenate([spec.ravel(trained), w0[spec.n:]])
 
 
 def _fed_arrays(cfg: SimConfig, hp: H2FedParams, fed: FederatedData, *,
@@ -539,6 +546,9 @@ def run_simulation(cfg: SimConfig, hp: H2FedParams, het: HeterogeneityModel,
                               eval_fn=eval_fn)
 
 
+_unravel = jax.jit(flatten.FlatSpec.unravel, static_argnums=0)
+
+
 def _run_sync(res, init_params: PyTree, *,
               loss_fn: Callable = mlp.loss_fn,
               eval_fn: Optional[Callable] = None,
@@ -567,11 +577,13 @@ def _run_sync(res, init_params: PyTree, *,
             round_fn = make_flat_global_round(cfg, hp, het, fed, spec,
                                               loss_fn, fused=fused,
                                               faults=s.faults)
-            # eval_fn is called eagerly (unravel is cheap outside jit) so
-            # user-supplied non-traceable metrics keep working; the
-            # built-in accuracy eval_fn above is already jitted.
+            # eval_fn is called eagerly so user-supplied non-traceable
+            # metrics keep working; the built-in accuracy eval_fn above is
+            # already jitted.  The unravel before it is one jitted call:
+            # eager, its per-leaf slices cost ~2.5 ms of host a round on a
+            # TPU v5e host, as long as the paper cell's device round.
             eval_state = (None if eval_fn is None else
-                          (lambda s: eval_fn(spec.unravel(s.cloud_flat))))
+                          (lambda s: eval_fn(_unravel(spec, s.cloud_flat))))
             finalize = lambda s: from_flat_state(spec, s)    # noqa: E731
         elif engine == "tree":
             state = init_state(cfg, init_params, key)

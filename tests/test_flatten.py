@@ -232,3 +232,143 @@ class TestEngineEquivalence:
         with pytest.raises(ValueError):
             make_global_round(cfg, h2fed(), HeterogeneityModel(), fed,
                               engine="nope")
+
+
+class TestLeafCarryTraining:
+    """``simulator._local_train_flat`` takes and returns flat rows, but its
+    minibatch scan carries the model's leaves: the flat (N,) carry made
+    every step relayout the 784x40 weight on a TPU."""
+
+    A, SAMPLES, BATCH = 4, 12, 4          # three minibatches an epoch
+
+    @pytest.fixture(scope="class")
+    def paper_mlp(self):
+        from repro.configs.mnist_mlp import CONFIG as MLP_CFG
+        from repro.models import mlp
+        params = mlp.init_params(MLP_CFG, jax.random.key(3))
+        rng = np.random.default_rng(3)
+        x = jnp.asarray(rng.uniform(0, 1.5, (self.A, self.SAMPLES, 784)), F32)
+        y = jnp.asarray(rng.integers(0, 10, (self.A, self.SAMPLES)))
+        return mlp.loss_fn, params, x, y
+
+    def _train(self, loss_fn, spec, x, y, w_start, w_cloud, n_steps, act):
+        from repro.core.baselines import h2fed
+        from repro.fedsim.simulator import _local_train_flat
+        hp = h2fed(mu1=0.05, mu2=0.02, lar=2, lr=0.1)
+        train = jax.vmap(
+            lambda x, y, w0, wr, wc, a: _local_train_flat(
+                loss_fn, spec, x, y, w0, wr, wc, hp, n_steps, a,
+                self.BATCH),
+            in_axes=(0, 0, 0, 0, None, 0))
+        return hp, train, (x, y, w_start, w_start, w_cloud, act)
+
+    @pytest.mark.parametrize("storage,leaf", [
+        ("float32", "float32"), ("bfloat16", "float32"),
+        ("float32", "bfloat16")],
+        ids=["f32", "bf16_storage", "bf16_leaf"])
+    @pytest.mark.parametrize("n_steps", [1, 3])
+    def test_matches_per_leaf_reference(self, paper_mlp, n_steps, storage,
+                                        leaf):
+        """Bit for bit on the CPU against a per-leaf proximal-SGD scan on
+        fp32 leaves, with one agent at 0 steps, one partial and the rest
+        full.  bf16 storage rows widen to fp32 at entry; a bf16 leaf of
+        the template is trained in fp32 and cast only where the loss
+        reads it."""
+        from repro.data.pipeline import agent_minibatch
+        loss_fn, params, x, y = paper_mlp
+        params = dict(params, w1=params["w1"].astype(leaf))
+        spec = flatten.spec_of(params, storage_dtype=storage)
+        rng = np.random.default_rng(n_steps)
+        rows = jnp.asarray(rng.standard_normal((self.A, spec.n)) * 0.1, F32)
+        w_start = spec.to_storage(rows)
+        w_cloud = jnp.asarray(rng.standard_normal(spec.n) * 0.1, F32)
+        act = jnp.asarray([0, 1, n_steps, n_steps], jnp.int32)
+        hp, train, args = self._train(loss_fn, spec, x, y, w_start, w_cloud,
+                                      n_steps, act)
+        got = jax.jit(train)(*args)
+        assert got.shape == (self.A, spec.n) and got.dtype == jnp.float32
+
+        def fp32_leaves(row):
+            return jax.tree_util.tree_unflatten(spec.treedef, [
+                row[o:o + n].astype(F32).reshape(s) for o, n, s in
+                zip(spec.offsets, spec.sizes, spec.shapes)])
+
+        grad = jax.grad(lambda w, xb, yb: loss_fn(
+            dict(w, w1=w["w1"].astype(leaf)), xb, yb))
+        cloud = fp32_leaves(w_cloud)
+
+        def one_agent(x, y, row, a):
+            start = fp32_leaves(row)
+
+            def step(w, s):
+                g = grad(w, *agent_minibatch(x, y, s, self.BATCH))
+                live = (s < a).astype(jnp.float32)
+                return jax.tree.map(
+                    lambda wl, gl, r, c: wl - hp.lr * live * (
+                        gl + hp.mu1 * (wl - r) + hp.mu2 * (wl - c)),
+                    w, g, start, cloud), None
+
+            w, _ = jax.lax.scan(step, start, jnp.arange(n_steps))
+            return jnp.concatenate([l.reshape(-1)
+                                    for l in jax.tree.leaves(w)])
+
+        want = jax.jit(jax.vmap(one_agent))(x, y, w_start, act)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        # an agent with no completed step hands back its (widened) start
+        np.testing.assert_array_equal(
+            np.asarray(got[0]), np.asarray(w_start[0].astype(jnp.float32)))
+        assert not np.array_equal(np.asarray(got[1]),
+                                  np.asarray(w_start[1].astype(jnp.float32)))
+
+    def test_padded_tail_passes_through(self, paper_mlp):
+        """N-sharded and N-tiled engines pass rows padded past ``spec.n``
+        (tails equal across start rows and cloud): the model's columns
+        train as unpadded rows do, and the tail comes back unchanged."""
+        loss_fn, params, x, y = paper_mlp
+        spec = flatten.spec_of(params)
+        rng = np.random.default_rng(5)
+        rows = jnp.asarray(rng.standard_normal((self.A, spec.n)) * 0.1, F32)
+        cloud = jnp.asarray(rng.standard_normal(spec.n) * 0.1, F32)
+        tail = jnp.asarray(rng.standard_normal(190), F32)
+        act = jnp.asarray([0, 1, 3, 3], jnp.int32)
+        _, train, args = self._train(loss_fn, spec, x, y, rows, cloud, 3, act)
+        want = jax.jit(train)(*args)
+        tails = jnp.broadcast_to(tail, (self.A, 190))
+        padded = (jnp.concatenate([rows, tails], axis=1),
+                  jnp.concatenate([cloud, tail]))
+        _, train, args = self._train(loss_fn, spec, x, y, *padded, 3, act)
+        got = jax.jit(train)(*args)
+        assert got.shape == (self.A, spec.n + 190)
+        np.testing.assert_array_equal(np.asarray(got[:, :spec.n]),
+                                      np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(got[:, spec.n:]),
+                                      np.asarray(tails))
+
+    def test_scan_carries_leaves_not_the_flat_row(self, paper_mlp):
+        """The minibatch scan's carry is the (A, 784, 40) weight and its
+        siblings; no (A, N) vector is carried from step to step."""
+        from jax.extend.core import ClosedJaxpr, Jaxpr
+        loss_fn, params, x, y = paper_mlp
+        spec = flatten.spec_of(params)
+        w_start = jnp.zeros((self.A, spec.n), F32)
+        _, train, args = self._train(loss_fn, spec, x, y, w_start,
+                                     jnp.zeros((spec.n,), F32), 3,
+                                     jnp.full((self.A,), 3, jnp.int32))
+
+        def scan_carries(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "scan":
+                    k, c = eqn.params["num_consts"], eqn.params["num_carry"]
+                    yield sorted(tuple(v.aval.shape)
+                                 for v in eqn.invars[k:k + c])
+                for p in eqn.params.values():
+                    for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                        if isinstance(sub, ClosedJaxpr):
+                            yield from scan_carries(sub.jaxpr)
+                        elif isinstance(sub, Jaxpr):
+                            yield from scan_carries(sub)
+
+        carries = list(scan_carries(jax.make_jaxpr(train)(*args).jaxpr))
+        leaves = sorted((self.A,) + s for s in spec.shapes)
+        assert (self.A, 784, 40) in leaves
+        assert carries == [leaves]
