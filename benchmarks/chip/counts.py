@@ -1,10 +1,8 @@
 """Work and bytes of one cell, computed from shapes, and the chip's peaks.
 
-* ``mlp_flops_per_sample``: the matrix-product operations one training
-  sample needs in an MLP step: the forward product, the weight gradient,
-  and the input gradient of every layer but the first (the data needs no
-  gradient).  Bias adds, activations and the update are left out: they are
-  under 2% of the products at these widths.
+* The model's parameter count and the operations of one training sample
+  come from its kind's module (``models/<kind>.py``: ``n_params``,
+  ``flops_per_sample``), which the cell carries as ``cell.model``.
 * ``agg_blend_bytes`` / ``cloud_blend_bytes``: the fewest bytes an RSU or
   cloud aggregation call must move: the rows that carry weight are read
   once, and the rows that change are written once.  An agent that did not
@@ -17,7 +15,7 @@ from __future__ import annotations
 import json
 import re
 from pathlib import Path
-from typing import Dict, Optional, Sequence
+from typing import Dict, Optional
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 
@@ -29,22 +27,6 @@ def peaks(device_kind: str) -> Dict:
         raise KeyError(f"no peaks for device kind {device_kind!r} "
                        f"(known: {sorted(table)})")
     return table[device_kind]
-
-
-def layer_dims(config: Dict) -> Sequence[int]:
-    return ([config["input_dim"]] + list(config["hidden_dims"])
-            + [config["n_classes"]])
-
-
-def n_params(config: Dict) -> int:
-    d = layer_dims(config)
-    return sum(a * b + b for a, b in zip(d[:-1], d[1:]))
-
-
-def mlp_flops_per_sample(config: Dict) -> int:
-    d = layer_dims(config)
-    prods = [a * b for a, b in zip(d[:-1], d[1:])]
-    return 2 * sum(prods) + 2 * sum(prods) + 2 * sum(prods[1:])
 
 
 def agg_blend_bytes(n_connected: int, n_rsus_hit: int, n: int,
@@ -94,7 +76,7 @@ def _itemsize(config: Dict) -> int:
 def agg_least_seconds(ctx) -> float:
     """Least time of the window's aggregation calls: one RSU call per local
     round and one cloud call per global round."""
-    n = n_params(ctx.cell.config)
+    n = ctx.cell.model.n_params(ctx.cell.config)
     size = _itemsize(ctx.cell.config)
     peak = peaks(ctx.device["kind"])
     d = ctx.draws
@@ -109,5 +91,6 @@ def agg_least_seconds(ctx) -> float:
 
 def step_flops(ctx) -> float:
     """Operations of the window's local steps that reach an RSU."""
-    return (float(ctx.draws["live_steps"].sum()) * ctx.cell.traffic["batch"]
-            * mlp_flops_per_sample(ctx.cell.config))
+    cell = ctx.cell
+    return (float(ctx.draws["live_steps"].sum()) * cell.traffic["batch"]
+            * cell.model.flops_per_sample(cell.config, cell.traffic))

@@ -2,14 +2,19 @@
 the plain reference, and the metrics.
 
 Everything that belongs to one cell is found by name: the entry in
-``BENCHMARK.json``, ``configs/<config>.json`` (sizes, dtype, precision),
-``traffic/<mix>.json`` (fleet, data recipe, heterogeneity), ``limits/<cell>.json``
-(the limit of each number compared) and ``metrics/<metric>.py`` (one reader
-per metric).  Adding a cell, a configuration, a mix or a metric adds files
-and entries; nothing here changes, as long as the mix keeps to what this
-harness drives: the flat engine on scenario-II data, with exactly the keys
-of ``TRAFFIC_KEYS``.  A mix that asks for anything else is refused, since
-its knobs would reach neither the program nor the reference.
+``BENCHMARK.json``, ``configs/<config>.json`` (sizes, dtype, precision,
+and ``"model"``, the model kind), ``models/<kind>.py`` (what depends on
+the model: its data recipe, the loss the program trains with, the plain
+reference model and the work counts; ``MODEL_NAMES``),
+``traffic/<mix>.json`` (fleet, data recipe, heterogeneity),
+``limits/<cell>.json`` (the limit of each number compared) and
+``metrics/<metric>.py`` (one reader per metric).  Adding a cell, a
+configuration, a model kind, a mix or a metric adds files and entries;
+nothing here changes, as long as the mix keeps to what this harness
+drives: the flat engine, with exactly the keys of ``ALGORITHM_KEYS`` and
+of the model kind's ``DATA_KEYS``, on a partition the kind builds.  A mix
+that asks for anything else is refused, since its knobs would reach
+neither the program nor the reference.
 
 The window drives ``repro.fedsim.run_scenario`` with the flat engine, the
 entry users call, from the pretrained model, sized from the warm-up to
@@ -30,7 +35,7 @@ import statistics
 import sys
 import time
 from pathlib import Path
-from types import SimpleNamespace
+from types import ModuleType, SimpleNamespace
 from typing import Callable, Dict, List, Optional
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -54,16 +59,21 @@ EVAL_SPAN = "bench.eval"
 TRACE_AFTER = 1       # the traced rounds start after the call's first
 TRACE_SECONDS = 2.0   # and last about this long
 TRACE_DIR = ROOT / "results" / "bench_chip_trace"
-# every key a traffic mix may hold; each reaches the spec, the data or the
-# reference, and a mix holds all of them
-TRAFFIC_KEYS = frozenset((
+# the keys of a traffic mix that the harness itself drives, whatever the
+# model: the fleet, the H2-Fed hyper-parameters, heterogeneity and the run;
+# a mix holds all of them and its model kind's DATA_KEYS, and nothing else
+ALGORITHM_KEYS = frozenset((
     "n_agents", "n_rsus", "samples_per_agent", "batch", "lar",
     "local_epochs", "lr", "mu1", "mu2", "csr", "scd", "fsr", "partition",
-    "labels_per_agent", "n_train", "n_test", "noise", "data_seed",
-    "excluded_labels", "pretrain_frac", "oem_pool", "pretrain_target",
-    "pretrain_lr", "pretrain_max_epochs", "engine", "eval_every"))
+    "data_seed", "engine", "eval_every"))
 ENGINE = "flat"              # the engine the reference follows
-PARTITION = "scenario_two"   # the partition datagen builds
+# what a model kind's module gives (models/<kind>.py)
+MODEL_NAMES = ("DATA_KEYS", "PARTITIONS", "make", "spec_fields",
+               "program_loss", "loss", "evaluate", "n_params",
+               "flops_per_sample")
+# the numbers of the program against the reference (``numbers``); a cell's
+# limits file gives each a limit or names it under readings.not_compared
+NUMBERS = ("loss", "acc", "change3", "update1_median", "change3_median")
 
 
 @dataclasses.dataclass
@@ -71,6 +81,7 @@ class Cell:
     name: str
     chips: int
     config: Dict
+    model: ModuleType        # models/<config["model"]>.py
     traffic: Dict
     limits: Dict
     end_to_end: List[Dict]
@@ -92,30 +103,87 @@ def load_cell(name: str, bench: Optional[Dict] = None) -> Cell:
     def applies(m):
         return "workloads" not in m or name in m["workloads"]
 
+    config = json.loads((ROOT / conf["file"]).read_text())
+    if "model" not in config:
+        raise SystemExit(f"config {conf['name']!r} names no model kind "
+                         f"(its \"model\" key)")
+    model = load_model(config["model"])
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=json.loads((ROOT / conf["file"]).read_text()),
+        name=name, chips=int(w["chips"]), config=config, model=model,
         traffic=check_traffic(w["traffic"], json.loads(
-            (CHIP / "traffic" / f"{w['traffic']}.json").read_text())),
-        limits=json.loads((CHIP / "limits" / f"{name}.json").read_text()),
+            (CHIP / "traffic" / f"{w['traffic']}.json").read_text()), model),
+        limits=check_limits(name, json.loads(
+            (CHIP / "limits" / f"{name}.json").read_text())),
         end_to_end=[m for m in bench["end_to_end"] if applies(m)],
         per_layer=[m for m in bench["per_layer"] if applies(m)])
 
 
-def check_traffic(mix: str, traffic: Dict) -> Dict:
-    """``traffic`` if this harness drives all of it; else the run exits:
-    a key it would drop, a missing key, or an engine or partition that the
-    reference and the data generator do not follow."""
-    unknown = sorted(set(traffic) - TRAFFIC_KEYS)
-    missing = sorted(TRAFFIC_KEYS - set(traffic))
+def _load_file(path: Path, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_model(kind: str) -> ModuleType:
+    """The module of model kind ``kind``, ``models/<kind>.py``; the run
+    exits where there is none or it lacks one of ``MODEL_NAMES``."""
+    path = CHIP / "models" / f"{kind}.py"
+    if not path.is_file():
+        raise SystemExit(f"model kind {kind!r}: no file models/{kind}.py")
+    mod = _load_file(path, f"bench_chip_model_{kind}")
+    missing = [n for n in MODEL_NAMES if not hasattr(mod, n)]
+    if missing:
+        raise SystemExit(f"model kind {kind!r}: models/{kind}.py lacks "
+                         f"{missing}")
+    return mod
+
+
+def check_traffic(mix: str, traffic: Dict, model: ModuleType) -> Dict:
+    """``traffic`` if this harness and the model kind drive all of it; else
+    the run exits: a key neither would read, a missing key, an engine the
+    reference does not follow, or a partition the kind does not build."""
+    keys = ALGORITHM_KEYS | frozenset(model.DATA_KEYS)
+    unknown = sorted(set(traffic) - keys)
+    missing = sorted(keys - set(traffic))
     if unknown or missing:
         raise SystemExit(f"traffic {mix!r}: keys the harness does not drive "
                          f"{unknown}, missing {missing}")
-    if traffic["engine"] != ENGINE or traffic["partition"] != PARTITION:
+    if (traffic["engine"] != ENGINE
+            or traffic["partition"] not in model.PARTITIONS):
         raise SystemExit(f"traffic {mix!r}: the harness drives engine "
-                         f"{ENGINE!r} on partition {PARTITION!r}, not "
-                         f"{traffic['engine']!r} on {traffic['partition']!r}")
+                         f"{ENGINE!r} on partitions {list(model.PARTITIONS)}"
+                         f", not {traffic['engine']!r} on "
+                         f"{traffic['partition']!r}")
     return traffic
+
+
+def compared(limits: Dict) -> List[str]:
+    """The numbers a limits file compares, in the order of ``NUMBERS``."""
+    return [k for k in NUMBERS if k in limits]
+
+
+def check_limits(cell: str, limits: Dict) -> Dict:
+    """``limits`` if it gives at least one of ``NUMBERS`` a limit, a finite
+    number of at least 0, and names each of the others under
+    ``readings.not_compared``; else the run exits: a misspelt key, a number
+    both compared and named as not compared, or one left unaccounted for
+    would compare less than it seems to."""
+    keys = set(limits) - {"readings"}
+    skipped = set(limits.get("readings", {}).get("not_compared", {}))
+    unknown = sorted((keys | skipped) - set(NUMBERS))
+    unaccounted = sorted(set(NUMBERS) - keys - skipped)
+    bad = sorted(k for k in keys if isinstance(limits[k], bool)
+                 or not isinstance(limits[k], (int, float))
+                 or not 0 <= limits[k] < math.inf)
+    if not keys or unknown or unaccounted or bad or keys & skipped:
+        raise SystemExit(
+            f"limits of {cell!r}: compare at least one of {list(NUMBERS)} "
+            f"and name each other under readings.not_compared; compared "
+            f"{sorted(keys)}, not compared {sorted(skipped)}, unknown "
+            f"{unknown}, unaccounted for {unaccounted}, no limit {bad}")
+    return limits
 
 
 def sim_seed(seed: int) -> int:
@@ -184,13 +252,14 @@ class CompileCounter:
 
 class Eval:
     """The ``eval_fn`` handed to ``run_scenario``: test accuracy of the
-    cloud model, a host-clock stamp once it is on the host, a host copy of
-    the first ``capture`` cloud models, and, where asked, the profiler
-    started after round ``trace[0]`` and stopped after round ``trace[1]``,
-    with the host span ``TRACED_SPAN`` over the traced rounds."""
+    cloud model by the model kind's ``evaluate``, a host-clock stamp once
+    it is on the host, a host copy of the first ``capture`` cloud models,
+    and, where asked, the profiler started after round ``trace[0]`` and
+    stopped after round ``trace[1]``, with the host span ``TRACED_SPAN``
+    over the traced rounds."""
 
-    def __init__(self, x_test, y_test):
-        self.x, self.y = x_test, y_test
+    def __init__(self, evaluate: Callable, x_test, y_test):
+        self.evaluate, self.x, self.y = evaluate, x_test, y_test
         self.reset(0)
 
     def reset(self, capture: int, trace=None):
@@ -199,7 +268,7 @@ class Eval:
 
     def __call__(self, params):
         with jax.profiler.TraceAnnotation(EVAL_SPAN):
-            _, acc = reference.evaluate(params, self.x, self.y)
+            _, acc = self.evaluate(params, self.x, self.y)
             acc = float(acc)
             self.stamps.append(time.perf_counter())
             if len(self.captured) < self.capture:
@@ -217,8 +286,9 @@ class Eval:
 
 
 def scenario(cell: Cell, data: datagen.CellData, seed: int):
-    """The ``ResolvedScenario`` of the cell: its spec, and the benchmark's
-    data in the program's ``FederatedData``."""
+    """The ``ResolvedScenario`` of the cell: its spec, with the model
+    kind's fields, and the benchmark's data in the program's
+    ``FederatedData``."""
     from repro.core.h2fed import H2FedParams
     from repro.core.heterogeneity import HeterogeneityModel
     from repro.core.scenario import ResolvedScenario, ScenarioSpec
@@ -226,18 +296,15 @@ def scenario(cell: Cell, data: datagen.CellData, seed: int):
     t, c = cell.traffic, cell.config
     spec = ScenarioSpec(
         n_agents=t["n_agents"], n_rsus=t["n_rsus"], batch=t["batch"],
-        n_train=t["n_train"], n_test=t["n_test"], noise=t["noise"],
-        excluded_labels=tuple(t["excluded_labels"]),
-        pretrain_frac=t["pretrain_frac"],
-        pretrain_target=t["pretrain_target"], partition=t["partition"],
+        partition=t["partition"],
         hp=H2FedParams(mu1=t["mu1"], mu2=t["mu2"], lar=t["lar"],
                        local_epochs=t["local_epochs"], lr=t["lr"]),
         het=HeterogeneityModel(csr=t["csr"], scd=t["scd"], fsr=t["fsr"],
                                lar=t["lar"]),
         engine=t["engine"], fleet_dtype=c["fleet_dtype"],
-        hidden_dims=tuple(c["hidden_dims"]), eval_every=t["eval_every"],
-        rounds=MIN_ROUNDS, seed=int(t["data_seed"]),
-        sim_seed=sim_seed(seed)).validate()
+        eval_every=t["eval_every"], rounds=MIN_ROUNDS,
+        seed=int(t["data_seed"]), sim_seed=sim_seed(seed),
+        **cell.model.spec_fields(c, t)).validate()
     fed = FederatedData(x=data.x, y=data.y, n_per_agent=data.n_per_agent,
                         rsu_assign=data.rsu_assign)
     return ResolvedScenario(spec=spec, train=None, test=None,
@@ -250,15 +317,18 @@ def program_seed(res) -> int:
     return res.spec.seed * 1000 + res.spec.sim_seed
 
 
-def timed_call(res, params, ev: Eval, rounds: int, precision: str):
-    """One ``run_scenario`` call of ``rounds`` rounds under the
-    configuration's matmul precision; returns (start, end, history)."""
+def timed_call(cell: Cell, res, params, ev: Eval, rounds: int):
+    """One ``run_scenario`` call of ``rounds`` rounds on the model kind's
+    ``program_loss``, under the configuration's matmul precision; returns
+    (start, end, history)."""
     from repro.fedsim import sweep
     res = dataclasses.replace(res, spec=res.spec.replace(rounds=rounds))
-    with jax.default_matmul_precision(precision):
+    loss_fn = cell.model.program_loss(cell.config)
+    with jax.default_matmul_precision(cell.config["matmul_precision"]):
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation(WINDOW_SPAN):
-            final, hist = sweep.run_scenario(res, params, eval_fn=ev)
+            final, hist = sweep.run_scenario(res, params, loss_fn=loss_fn,
+                                             eval_fn=ev)
             jax.block_until_ready(final)
         t1 = time.perf_counter()
     del final
@@ -288,20 +358,47 @@ def leaf_gap(prog: Dict, ref: Dict, base: Dict) -> float:
     return max(abs(dp[k] - dr[k]) / max(dr[k], med) for k in kept)
 
 
-def numbers(prog: List[Dict], ref: List[Dict], base: Dict, x_test,
-            y_test) -> Dict[str, float]:
-    """The numbers compared: per round, the test loss (relative gap) and
-    the test accuracy (absolute gap) of the cloud model; the round-1
-    update and the change over all compared rounds, by the worst leaf."""
-    ev_p = [tuple(map(float, reference.evaluate(p, x_test, y_test)))
-            for p in prog]
-    ev_r = [tuple(map(float, reference.evaluate(r, x_test, y_test)))
-            for r in ref]
+def median_gap(prog: Dict, ref: Dict, base: Dict) -> float:
+    """Worst leaf's median element of the gap between the program's and
+    the reference's change from ``base``, over the median element of the
+    reference's change of that leaf, both taken over the elements that the
+    reference moves; leaves as in ``leaf_gap``.  An element the reference
+    leaves exactly where it was, as a vocabulary row no batch touched or an
+    expert nothing was routed to, stays out, so a leaf that is mostly such
+    elements still has a median to divide by.  A gap confined to a few
+    elements, as one sample's term that one side's ReLU gate lets through
+    and the other's does not, leaves it unmoved; a lower precision or a
+    wrong step moves every element."""
+    _, kept = _kept(_leaf_norms(ref, base))
+    out = 0.0
+    for k in kept:
+        b = np.asarray(base[k], np.float64)
+        dr = np.asarray(ref[k], np.float64) - b
+        moved = dr != 0
+        dp = np.asarray(prog[k], np.float64)[moved] - b[moved]
+        dr = dr[moved]
+        out = max(out, float(np.median(np.abs(dp - dr))
+                             / np.median(np.abs(dr))))
+    return out
+
+
+def numbers(cell: Cell, prog: List[Dict], ref: List[Dict], base: Dict,
+            x_test, y_test) -> Dict[str, float]:
+    """The numbers of the program against the reference, ``NUMBERS``: per
+    round, the test loss (relative gap) and the test accuracy (absolute
+    gap) of the cloud model, by the model kind's ``evaluate``; the change
+    over all compared rounds by the worst leaf's norm; the round-1 update
+    and that change by the median element.  Each is compared, or named as
+    not compared in the cell's limits file (``check_limits``)."""
+    evaluate = cell.model.evaluate
+    ev_p = [tuple(map(float, evaluate(p, x_test, y_test))) for p in prog]
+    ev_r = [tuple(map(float, evaluate(r, x_test, y_test))) for r in ref]
     return {
         "loss": max(abs(p[0] - r[0]) / r[0] for p, r in zip(ev_p, ev_r)),
         "acc": max(abs(p[1] - r[1]) for p, r in zip(ev_p, ev_r)),
-        "update1": leaf_gap(prog[0], ref[0], base),
         "change3": leaf_gap(prog[-1], ref[-1], base),
+        "update1_median": median_gap(prog[0], ref[0], base),
+        "change3_median": median_gap(prog[-1], ref[-1], base),
     }
 
 
@@ -309,16 +406,13 @@ def reference_rounds(cell: Cell, data: datagen.CellData, res,
                      mode: str = "fp32") -> List[Dict]:
     return reference.simulate(
         data.params, data.x, data.y, data.n_per_agent, data.rsu_assign,
-        cell.traffic, program_seed(res), COMPARED, mode=mode)
+        cell.traffic, program_seed(res), COMPARED, loss=cell.model.loss,
+        mode=mode)
 
 
 def load_metric(name: str) -> Callable:
-    path = CHIP / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_chip_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_file(CHIP / "metrics" / f"{name}.py",
+                      f"bench_chip_metric_{name.replace('.', '_')}").read
 
 
 def read_metrics(entries: List[Dict], ctx) -> Dict:
@@ -335,11 +429,17 @@ def log(*a):
 
 
 def prepare(cell: Cell, seed: int):
-    """The cell's data, pretrained model and scenario for ``seed``."""
-    dims = ([cell.config["input_dim"]] + list(cell.config["hidden_dims"])
-            + [cell.config["n_classes"]])
-    data = datagen.make(cell.traffic, tuple(dims), sim_seed(seed))
+    """The cell's data, starting model and scenario for ``seed``, from the
+    model kind's ``make``; a fleet of another shape than the mix states
+    ends the run."""
+    t = cell.traffic
+    data = cell.model.make(t, cell.config, sim_seed(seed))
     jax.block_until_ready((data.x, data.params))
+    fleet = tuple(data.x.shape[:2])
+    if fleet != (t["n_agents"], t["samples_per_agent"]):
+        raise SystemExit(f"{cell.name}: the data holds {fleet} agents x "
+                         f"samples, the mix states "
+                         f"{(t['n_agents'], t['samples_per_agent'])}")
     return data, scenario(cell, data, seed)
 
 
@@ -356,8 +456,6 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
 
 def _run(cell, seed, seconds, trace, process_start, device, compiles):
-    precision = cell.config["matmul_precision"]
-
     t0 = time.perf_counter()
     data, res = prepare(cell, seed)
     data_setup_s = time.perf_counter() - t0
@@ -366,7 +464,7 @@ def _run(cell, seed, seconds, trace, process_start, device, compiles):
         f"after {data.pre_epochs} epochs, {data_setup_s:.3f} s")
 
     t1 = time.perf_counter()
-    ev = Eval(data.x_test, data.y_test)
+    ev = Eval(cell.model.evaluate, data.x_test, data.y_test)
     round_s = overhead = None
     # the first call loads (or compiles) the round; the window is sized
     # from the later half of the next, since the rounds just after a
@@ -374,7 +472,7 @@ def _run(cell, seed, seconds, trace, process_start, device, compiles):
     n = WARM_ROUNDS
     for call in range(3):
         c0 = compiles.compiles
-        a, _, _ = timed_call(res, data.params, ev, n, precision)
+        a, _, _ = timed_call(cell, res, data.params, ev, n)
         d = np.diff([a] + ev.stamps)
         round_s = float(np.median(d[len(d) // 2:]))
         overhead = float(d[0] - round_s)
@@ -397,7 +495,7 @@ def _run(cell, seed, seconds, trace, process_start, device, compiles):
     ev.reset(COMPARED, traced and (*traced, str(trace_dir)))
     c0 = compiles.compiles
     setup_s = time.perf_counter() - process_start
-    start, end, hist = timed_call(res, data.params, ev, rounds, precision)
+    start, end, hist = timed_call(cell, res, data.params, ev, rounds)
     window_compiles = compiles.compiles - c0
     log(f"window: {rounds} rounds in {end - start:.6f} s, "
         f"{window_compiles} backend compiles in the window")
@@ -422,11 +520,11 @@ def _run(cell, seed, seconds, trace, process_start, device, compiles):
 
     t2 = time.perf_counter()
     ref = reference_rounds(cell, data, res)
-    got = numbers(ev.captured, ref, jax.device_get(data.params),
+    got = numbers(cell, ev.captured, ref, jax.device_get(data.params),
                   data.x_test, data.y_test)
     log(f"reference: {COMPARED} rounds in {time.perf_counter() - t2:.3f} s")
-    checks = {k: {"value": v, "limit": cell.limits[k]}
-              for k, v in got.items() if k in cell.limits}
+    checks = {k: {"value": got[k], "limit": cell.limits[k]}
+              for k in compared(cell.limits)}
     correct = failed == 0 and all(
         math.isfinite(c["value"]) and c["value"] <= c["limit"]
         for c in checks.values())

@@ -41,7 +41,6 @@ def main(argv=None) -> int:
     harness.use_compile_cache()
     cell = harness.load_cell(args.workload)
     harness.check_device(cell.chips)
-    precision = cell.config["matmul_precision"]
     ints = [int(s) for s in args.seeds.split(",") if s]
     control = {int(s) for s in args.control_seeds.split(",") if s}
     planted = [f for f in args.faults.split(",") if f]
@@ -59,29 +58,29 @@ def main(argv=None) -> int:
         t0 = time.perf_counter()
         data, res = harness.prepare(cell, seed)
         base = harness.jax.device_get(data.params)
-        ev = harness.Eval(data.x_test, data.y_test)
+        ev = harness.Eval(cell.model.evaluate, data.x_test, data.y_test)
 
         def program():
             ev.reset(harness.COMPARED)
-            harness.timed_call(res, data.params, ev, harness.COMPARED,
-                               precision)
+            harness.timed_call(cell, res, data.params, ev,
+                               harness.COMPARED)
             return ev.captured
 
         prog = program()
         ref = harness.reference_rounds(cell, data, res)
-        emit("program", seed, harness.numbers(prog, ref, base, data.x_test,
-                                              data.y_test),
+        emit("program", seed, harness.numbers(cell, prog, ref, base,
+                                              data.x_test, data.y_test),
              pre_acc=data.pre_acc, seconds=time.perf_counter() - t0)
         if seed not in control:
             continue
         ctrl = harness.reference_rounds(cell, data, res, mode="bf16x3")
-        emit("control", seed, harness.numbers(ctrl, ref, base,
+        emit("control", seed, harness.numbers(cell, ctrl, ref, base,
                                               data.x_test, data.y_test))
         for name in planted:
             with faults.FAULTS[name]():
                 bad = program()
-            emit(name, seed, harness.numbers(bad, ref, base, data.x_test,
-                                             data.y_test))
+            emit(name, seed, harness.numbers(cell, bad, ref, base,
+                                             data.x_test, data.y_test))
     return 0
 
 

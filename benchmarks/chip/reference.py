@@ -20,6 +20,11 @@ realization.  Agents are trained and summed in blocks, so the reference
 fits beside nothing else on the chip.  Matrix products go through
 ``make_dot``: fp32 at the highest precision, or the three-pass bfloat16
 product that is the control.
+
+The algebra is the same for every model: the model's own plain loss,
+``loss(params, x, y, dot)``, comes from its kind's module
+(``models/<kind>.py``) and is the only part of training that depends on
+the model.
 """
 from __future__ import annotations
 
@@ -74,32 +79,6 @@ def make_dot(mode: str) -> Callable:
 
     dot.defvjp(fwd, bwd)
     return dot
-
-
-def forward(params: Params, x, dot):
-    n = len(params) // 2
-    h = x
-    for i in range(n):
-        h = dot(h, params[f"w{i}"]) + params[f"b{i}"]
-        if i < n - 1:
-            h = jax.nn.relu(h)
-    return h
-
-
-def loss(params: Params, x, y, dot):
-    """Mean cross-entropy."""
-    logp = jax.nn.log_softmax(forward(params, x, dot), axis=-1)
-    return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=-1))
-
-
-@jax.jit
-def evaluate(params: Params, x, y):
-    """(test loss, test accuracy) in fp32."""
-    dot = make_dot("fp32")
-    logits = forward(params, x, dot)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=-1))
-    return nll, jnp.mean(jnp.argmax(logits, -1) == y)
 
 
 def draws(key, remaining, t: Dict, n_agents: int, spe: int):
@@ -157,12 +136,14 @@ def realized(sim_seed: int, t: Dict, n_agents: int, n_rounds: int,
             "cloud_rsus": cloud}
 
 
-@functools.partial(jax.jit, static_argnames=("t_items", "block", "mode"))
+@functools.partial(jax.jit,
+                   static_argnames=("t_items", "block", "mode", "loss"))
 def _train_block(start, x, y, share, assign, rsu, cloud, steps, *,
-                 t_items, block: int, mode: str):
-    """Train agents [start, start + block) from their RSU's model; return
-    their part of each RSU's weighted mean, where ``share`` is each agent's
-    weight in its RSU's mean (0 for an agent that did not reach it)."""
+                 t_items, block: int, mode: str, loss: Callable):
+    """Train agents [start, start + block) from their RSU's model on
+    ``loss``; return their part of each RSU's weighted mean, where
+    ``share`` is each agent's weight in its RSU's mean (0 for an agent
+    that did not reach it)."""
     t = dict(t_items)
     dot = make_dot(mode)
     n_rsus = jax.tree.leaves(rsu)[0].shape[0]
@@ -216,10 +197,11 @@ def _cloud(rsu, total, cloud, mode: str):
 
 
 def simulate(params: Params, x, y, n_per_agent, rsu_assign, t: Dict,
-             sim_seed: int, n_rounds: int, mode: str = "fp32"
-             ) -> List[Params]:
+             sim_seed: int, n_rounds: int, *, loss: Callable,
+             mode: str = "fp32") -> List[Params]:
     """The cloud model after each of ``n_rounds`` global rounds from
-    ``params``, with matrix products in ``mode``."""
+    ``params``, training on the model's plain ``loss`` with matrix
+    products in ``mode``."""
     n_agents, n = int(x.shape[0]), int(x.shape[1])
     n_rsus = t["n_rsus"]
     spe = max(n // t["batch"], 1)
@@ -259,7 +241,8 @@ def simulate(params: Params, x, y, n_per_agent, rsu_assign, t: Dict,
             for b in range(n_blocks):
                 part = _train_block(
                     b * block, xs, ys, share, assign, rsu, cloud,
-                    padded(steps), t_items=t_items, block=block, mode=mode)
+                    padded(steps), t_items=t_items, block=block, mode=mode,
+                    loss=loss)
                 mean = jax.tree.map(jnp.add, mean, part)
             rsu = jax.tree.map(
                 lambda ml, ol: jnp.where(

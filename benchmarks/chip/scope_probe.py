@@ -43,7 +43,7 @@ def _delta(before, after):
             for k in before}
 
 
-def traced_call(harness, res, data, ev, rounds, precision, trace_dir):
+def traced_call(harness, cell, res, data, ev, rounds, trace_dir):
     """One call of ``rounds`` + 2 rounds, rounds 2 to ``rounds`` + 1
     traced; returns its per-round seconds, its compile counters and the
     wall time of its traces counted once (nested traces overlap)."""
@@ -62,8 +62,8 @@ def traced_call(harness, res, data, ev, rounds, precision, trace_dir):
     before = _counters()
     jax.monitoring.register_event_duration_secs_listener(on_trace)
     try:
-        start, _, _ = harness.timed_call(res, data.params, ev, rounds + 2,
-                                         precision)
+        start, _, _ = harness.timed_call(cell, res, data.params, ev,
+                                         rounds + 2)
     finally:
         jax.monitoring.unregister_event_duration_listener(on_trace)
     from benchmarks.chip import trace_reduce
@@ -122,12 +122,11 @@ def main(argv=None) -> int:
     harness.use_compile_cache()
     cell = harness.load_cell(args.workload)
     device = harness.check_device(cell.chips)
-    precision = cell.config["matmul_precision"]
     data, res = harness.prepare(cell, args.seed)
-    ev = harness.Eval(data.x_test, data.y_test)
+    ev = harness.Eval(cell.model.evaluate, data.x_test, data.y_test)
     n = harness.WARM_ROUNDS
     for _ in range(2):
-        a, _, _ = harness.timed_call(res, data.params, ev, n, precision)
+        a, _, _ = harness.timed_call(cell, res, data.params, ev, n)
         d = np.diff([a] + ev.stamps)
         round_s = float(np.median(d[len(d) // 2:]))
         ev.reset(0)
@@ -136,7 +135,7 @@ def main(argv=None) -> int:
     trace_dir = harness.TRACE_DIR / "scope_probe"
 
     rounds = max(3, int(args.seconds / round_s))
-    call = traced_call(harness, res, data, ev, rounds, precision, trace_dir)
+    call = traced_call(harness, cell, res, data, ev, rounds, trace_dir)
     path = trace_reduce.find_xplane(str(trace_dir))
     summary = trace_reduce.reduce_file(path)
     split = scopes.reduce_file(path)
@@ -156,8 +155,8 @@ def main(argv=None) -> int:
         "call": {k: v for k, v in call.items() if k != "per_round_s"},
     }
     if args.keep:
-        kept = traced_call(harness, res, data, ev, args.keep_rounds,
-                           precision, trace_dir)
+        kept = traced_call(harness, cell, res, data, ev, args.keep_rounds,
+                           trace_dir)
         path = trace_reduce.find_xplane(str(trace_dir))
         Path(args.keep).parent.mkdir(parents=True, exist_ok=True)
         shutil.copyfile(path, args.keep)

@@ -14,6 +14,7 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental.compilation_cache.compilation_cache import \
     reset_cache
@@ -54,32 +55,35 @@ def cell():
 @pytest.fixture(scope="module")
 def rounds(cell):
     data, res = harness.prepare(cell, 11)
-    ev = harness.Eval(data.x_test, data.y_test)
+    ev = harness.Eval(cell.model.evaluate, data.x_test, data.y_test)
     ev.reset(harness.COMPARED)
-    harness.timed_call(res, data.params, ev, harness.COMPARED,
-                       cell.config["matmul_precision"])
+    harness.timed_call(cell, res, data.params, ev, harness.COMPARED)
     ref = harness.reference_rounds(cell, data, res)
     return data, res, ev.captured, ref
 
 
-def _numbers(data, got, ref):
-    return harness.numbers(got, ref, jax.device_get(data.params),
+def _numbers(cell, data, got, ref):
+    return harness.numbers(cell, got, ref, jax.device_get(data.params),
                            data.x_test, data.y_test)
 
 
 def test_the_program_passes_its_limits(cell, rounds):
     data, _, prog, ref = rounds
-    got = _numbers(data, prog, ref)
-    assert set(got) == set(cell.limits) - {"readings"}
-    for k, v in got.items():
-        assert v <= cell.limits[k], (k, v, cell.limits[k])
+    got = _numbers(cell, data, prog, ref)
+    compared = set(cell.limits) - {"readings"}
+    skipped = set(cell.limits["readings"].get("not_compared", {}))
+    assert compared and compared | skipped == set(got) == set(
+        harness.NUMBERS)
+    for k in compared:
+        assert got[k] <= cell.limits[k], (k, got[k], cell.limits[k])
 
 
 def test_the_control_fails_its_limits(cell, rounds):
     data, res, _, ref = rounds
     ctrl = harness.reference_rounds(cell, data, res, mode="bf16x3")
-    got = _numbers(data, ctrl, ref)
-    assert any(v > cell.limits[k] for k, v in got.items()), got
+    got = _numbers(cell, data, ctrl, ref)
+    assert any(v > cell.limits[k] for k, v in got.items()
+               if k in cell.limits), got
 
 
 def _run(cell, seed):
@@ -96,6 +100,68 @@ def test_a_sound_run_is_correct(cell):
     assert r["device"]["platform"] == "cpu"
 
 
+def test_a_gap_in_a_few_elements_leaves_the_median_gap_unmoved():
+    rng = np.random.default_rng(0)
+    base = {"w": rng.normal(size=(784, 40)), "b": rng.normal(size=40)}
+    ref = {k: v + rng.normal(size=v.shape) * 1e-2 for k, v in base.items()}
+    # one sample's term in one hidden unit: one column and one bias
+    flip = {"w": ref["w"].copy(), "b": ref["b"].copy()}
+    flip["w"][:, 19] += 1e-2
+    flip["b"][19] += 1e-2
+    assert harness.median_gap(flip, ref, base) == 0.0
+    assert harness.leaf_gap(flip, ref, base) > 1e-3
+    # a lower precision moves every element
+    low = {k: v * (1 + 1e-4) for k, v in ref.items()}
+    assert harness.median_gap(low, ref, base) > 1e-3
+    assert harness.median_gap(base, ref, base) == 1.0
+
+
+def test_a_leaf_the_reference_mostly_leaves_unmoved_has_a_median_gap():
+    # rows no batch touched: the reference moves 10 of 100 rows, exactly
+    rng = np.random.default_rng(1)
+    base = {"emb": rng.normal(size=(100, 8)), "b": rng.normal(size=8)}
+    ref = {k: v.copy() for k, v in base.items()}
+    ref["emb"][:10] += rng.normal(size=(10, 8)) * 1e-2
+    ref["b"] += rng.normal(size=8) * 1e-2
+    assert harness.median_gap(ref, ref, base) == 0.0
+    low = {k: base[k] + (ref[k] - base[k]) * (1 + 1e-4) for k in base}
+    assert harness.median_gap(low, ref, base) == pytest.approx(1e-4)
+    assert harness.median_gap(base, ref, base) == 1.0
+    # a gap in rows the reference left alone is the norm numbers' to see
+    stray = {k: v.copy() for k, v in ref.items()}
+    stray["emb"][50:] += 1e-2
+    assert harness.median_gap(stray, ref, base) == 0.0
+    assert harness.leaf_gap(stray, ref, base) > 1.0
+
+
+def _renamed(limits, old, new):
+    return {(new if k == old else k): v for k, v in limits.items()}
+
+
+LIMIT_EDITS = {
+    "compares_nothing": lambda l: {"readings": {"not_compared": {
+        k: {} for k in harness.NUMBERS}}},
+    "misspelt": lambda l: _renamed(l, "update1_median", "update1_med"),
+    "unaccounted_for": lambda l: {k: v for k, v in l.items()
+                                  if k != "change3"},
+    "compared_and_not": lambda l: {**l, "readings": {
+        **l["readings"], "not_compared": {"loss": {}}}},
+    "a_string": lambda l: {**l, "acc": "0.01"},
+    "nan": lambda l: {**l, "acc": float("nan")},
+    "negative": lambda l: {**l, "acc": -1.0},
+}
+
+
+@pytest.mark.parametrize("edit", sorted(LIMIT_EDITS))
+def test_limits_that_compare_less_than_they_seem_are_refused(cell, edit):
+    """Every number is compared or named as not compared, each limit is a
+    number of at least 0, and a file that compares nothing, misspells a
+    number or leaves one unaccounted for ends the run."""
+    assert harness.check_limits(PAPER, cell.limits) == cell.limits
+    with pytest.raises(SystemExit, match=f"limits of '{PAPER}'"):
+        harness.check_limits(PAPER, LIMIT_EDITS[edit](cell.limits))
+
+
 @pytest.mark.parametrize("fault", sorted(faults.FAULTS))
 def test_a_planted_fault_is_not_correct(cell, fault):
     with faults.FAULTS[fault]():
@@ -106,11 +172,11 @@ def test_a_planted_fault_is_not_correct(cell, fault):
 def test_a_compile_in_the_window_ends_the_run(cell, monkeypatch):
     timed = harness.timed_call
 
-    def compiling(res, params, ev, rounds, precision):
+    def compiling(cell, res, params, ev, rounds):
         if ev.capture:     # only the window keeps rounds for the check
             fresh = float(time.time_ns() % 1_000_003)
             jax.jit(lambda x: x * fresh)(jnp.ones(3)).block_until_ready()
-        return timed(res, params, ev, rounds, precision)
+        return timed(cell, res, params, ev, rounds)
 
     monkeypatch.setattr(harness, "timed_call", compiling)
     with pytest.raises(SystemExit, match="compiles in the measured window"):
@@ -124,8 +190,9 @@ def test_a_mix_the_harness_does_not_drive_is_refused(cell, change):
     mix = {k: v for k, v in {**cell.traffic, **change}.items()
            if v is not None}
     with pytest.raises(SystemExit, match="traffic 'mix'"):
-        harness.check_traffic("mix", mix)
-    assert harness.check_traffic("mix", dict(cell.traffic)) == cell.traffic
+        harness.check_traffic("mix", mix, cell.model)
+    assert harness.check_traffic("mix", dict(cell.traffic),
+                                 cell.model) == cell.traffic
 
 
 def _command(cwd, env_extra=None):
@@ -171,6 +238,8 @@ def test_benchmark_json_names_whole_files():
         cell = harness.load_cell(w["name"], bench)
         names = {m["name"] for m in cell.end_to_end}
         assert "setup_s" in names and len(names) >= 2 and cell.per_layer
-        assert {"loss", "acc", "update1", "change3"} <= set(cell.limits)
+        assert harness.compared(cell.limits)
+    paper = harness.load_cell(PAPER, bench)
+    assert harness.compared(paper.limits) == list(harness.NUMBERS)
     e2e = {m["name"] for m in bench["end_to_end"]}
     assert all(m["moves"] in e2e for m in bench["per_layer"])

@@ -14,14 +14,15 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 from benchmarks.chip import counts, harness, reference  # noqa: E402
 
 PAPER = json.loads((harness.CHIP / "configs" / "h2fed_mlp.json").read_text())
+MLP = harness.load_model(PAPER["model"])
 # the FedAvg paper's MNIST 2NN (arXiv 1602.05629), a wider MLP
 TWO_NN = {"input_dim": 784, "hidden_dims": [200, 200], "n_classes": 10,
           "n_params": 199_210}
 
 
 def test_parameter_counts_match_the_configs():
-    assert counts.n_params(PAPER) == PAPER["n_params"] == 31_810
-    assert counts.n_params(TWO_NN) == TWO_NN["n_params"] == 199_210
+    assert MLP.n_params(PAPER) == PAPER["n_params"] == 31_810
+    assert MLP.n_params(TWO_NN) == TWO_NN["n_params"] == 199_210
 
 
 @pytest.mark.parametrize("config, flops", [
@@ -33,7 +34,7 @@ def test_parameter_counts_match_the_configs():
     (TWO_NN, 4 * 198_800 + 2 * 42_000),
 ])
 def test_mlp_flops_per_sample(config, flops):
-    assert counts.mlp_flops_per_sample(config) == flops
+    assert MLP.flops_per_sample(config, {}) == flops
 
 
 def test_agg_blend_bytes_at_the_paper_shapes():
